@@ -37,6 +37,8 @@ from __future__ import annotations
 
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
+
 _CSV: list[str] = []
 
 
@@ -55,6 +57,7 @@ def print_csv(lines: list[str]) -> None:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("which", nargs="?", default="all",
                     choices=("all", "table1", "serve", "serve-async",

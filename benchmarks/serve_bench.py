@@ -19,6 +19,7 @@ import jax
 
 from benchmarks.table1 import modeled_row
 from repro.core import workload as W
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_cnn import serve
 
 SCHEMA_VERSION = 1
@@ -79,6 +80,7 @@ def run(emit, *, quick: bool = False, batch: int | None = None,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="AlexNet only, small batch (CI bench-smoke)")

@@ -51,6 +51,7 @@ import jax
 import numpy as np
 
 from repro.core import workload as W
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_cnn import compile_for_serving, serve_knee
 from repro.serving import (ChaosExecutor, FaultPlan, PipelineExecutor,
                            ReplicaPool, armed_class_names, default_mix,
@@ -307,6 +308,7 @@ def run(emit, *, quick: bool = False, batch: int | None = None,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="AlexNet only, small batch, fewer scenarios "

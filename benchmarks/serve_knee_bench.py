@@ -49,6 +49,7 @@ import time
 import jax
 
 from repro.core import workload as W
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve_cnn import (compile_for_serving, serve_knee,
                                     serve_knee_rescale)
 from repro.serving import parse_traffic_mix
@@ -249,6 +250,7 @@ def run(emit, *, quick: bool = False, batch: int | None = None,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="AlexNet only, small batch (CI bench-smoke)")

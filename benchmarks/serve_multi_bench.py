@@ -39,6 +39,7 @@ import time
 import jax
 
 from repro.core import workload as W
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (ProgramRegistry, ServerConfig, TrafficClass,
                            build_server, make_schedule, merge_schedules,
                            replay, tag_tenant)
@@ -280,6 +281,7 @@ def run(emit, *, quick: bool = False, batch: int | None = None,
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="two tenants (alexnet + zf), small batch "

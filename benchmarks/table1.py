@@ -17,6 +17,7 @@ from repro.core import throughput as T
 from repro.core import workload as W
 from repro.core.program import compile_model
 from repro.core.simulator import simulate
+from repro.launch.compile_cache import enable_compile_cache
 
 PAPER = {  # model: (DSP, eff, fps16, gops16, fps8, gops8)
     "vgg16": (900, 0.980, 11.3, 353, 22.6, 706),
@@ -153,6 +154,7 @@ def run(emit, models: list[str] | None = None, quick: bool = False):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     import argparse
     ap = argparse.ArgumentParser(description="Table I reproduction")
     ap.add_argument("--quick", action="store_true",

@@ -28,8 +28,12 @@ from repro.runtime import sharding as SH
 from repro.runtime.fault_tolerance import elastic_replan
 
 
+AUTO2 = (jax.sharding.AxisType.Auto,) * 2
+
+
 def mk_mesh(n_data, n_model):
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=AUTO2)
 
 
 def place(tree, shardings):
@@ -61,7 +65,7 @@ def main():
         print(f"[re-plan] surviving 4 chips -> stages x tp = "
               f"{plan.n_stages} x {plan.tensor_parallel}, "
               f"util {plan.utilization:.2f}")
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"),
+        mesh4 = jax.make_mesh((2, 2), ("data", "model"), axis_types=AUTO2,
                               devices=jax.devices()[:4])
         psh4 = SH.param_shardings(cfg, mesh4, params, fsdp=False)
         params4 = ckpt.restore_resharded(ckdir, 6, params, psh4)

@@ -21,8 +21,9 @@ from __future__ import annotations
 import os
 from typing import Any
 
-from repro.compiler.calibrate import (GoldenMismatch, check_golden,
-                                      golden_frames, load_golden,
+from repro.compiler.calibrate import (GoldenMismatch, assert_golden,
+                                      check_golden, golden_frames,
+                                      golden_record, load_golden,
                                       make_golden, quantize, save_golden)
 from repro.compiler.graph import (Graph, GraphError, Node,
                                   UnsupportedOpError, from_spec,
@@ -62,5 +63,6 @@ __all__ = [
     "from_spec", "load_spec", "load_onnx", "onnx_available",
     "lower_graph", "import_graph", "import_source",
     "quantize", "make_golden", "check_golden", "GoldenMismatch",
-    "golden_frames", "save_golden", "load_golden",
+    "golden_frames", "golden_record", "assert_golden", "save_golden",
+    "load_golden",
 ]

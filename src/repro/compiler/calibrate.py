@@ -31,7 +31,7 @@ import zlib
 import jax
 import numpy as np
 
-from repro.core.program import EngineProgram, compile_model
+from repro.core.program import EngineProgram, compile_model, host_device
 from repro.core.workload import CNNModel
 from repro.models import cnn
 
@@ -45,10 +45,12 @@ class GoldenMismatch(AssertionError):
 
 
 def calib_batch(model: CNNModel, n: int = 1, seed: int = 0):
-    """The seeded float calibration batch (activation-range pass)."""
-    return jax.random.normal(
-        jax.random.PRNGKey(seed + 1),
-        (n, model.input_hw, model.input_hw, model.input_ch))
+    """The seeded float calibration batch (activation-range pass), drawn
+    on the host CPU device like the weights."""
+    with jax.default_device(host_device()):
+        return jax.random.normal(
+            jax.random.PRNGKey(seed + 1),
+            (n, model.input_hw, model.input_hw, model.input_ch))
 
 
 def golden_frames(model: CNNModel, n: int = N_GOLDEN_FRAMES,
@@ -84,19 +86,28 @@ def quantize(model: CNNModel, params=None, *, bits: int = 8,
 def make_golden(prog: EngineProgram, frames: np.ndarray | None = None,
                 *, seed: int = 0, route: str = "f32") -> dict:
     """Generate the golden parity record for a compiled program (the
-    ``tests/golden/generate.py`` schema): first ``N_ACC_SAMPLE`` raw
-    int32 accumulators of frame 0, crc32 of the full accumulator
-    buffer, per-frame top-1 ids, and the frozen activation exponents."""
+    ``tests/golden/generate.py`` schema) by running ``frames`` (default:
+    the seeded golden frames) through a whole-chain runner on
+    ``route``."""
     if frames is None:
         frames = golden_frames(prog.model, seed=seed)
     runner = prog.compile_runner(route=route)
-    acc = np.asarray(runner(runner.quantize(np.asarray(frames))))
+    return golden_record(runner, runner(runner.quantize(np.asarray(frames))))
+
+
+def golden_record(runner, acc) -> dict:
+    """The golden record of ``acc``, the raw int32 final accumulators a
+    whole-chain ``runner`` produced: first ``N_ACC_SAMPLE`` of frame 0,
+    crc32 of the full buffer, per-frame top-1 ids, and the program's
+    frozen activation exponents."""
+    acc = np.asarray(acc)
     logits = runner.dequantize(acc)
+    prog = runner.program
     return {
         "acc_sample": acc[0].reshape(-1)[:N_ACC_SAMPLE].astype(np.int32),
         "acc_crc": np.int64(zlib.crc32(np.ascontiguousarray(acc)
                                        .tobytes())),
-        "top1": np.argmax(logits.reshape(len(frames), -1),
+        "top1": np.argmax(logits.reshape(len(acc), -1),
                           -1).astype(np.int64),
         "e_input": np.int64(prog.e_input),
         "e_out": np.asarray([s.e_out for s in prog.steps
@@ -111,7 +122,13 @@ def check_golden(prog: EngineProgram, golden, frames=None, *,
     diverging field. Checking on a *different* route than the one that
     generated the golden cross-checks the MAC lowerings against each
     other (f32 / int32-oracle / Pallas are bit-identical by contract)."""
-    got = make_golden(prog, frames, seed=seed, route=route)
+    assert_golden(make_golden(prog, frames, seed=seed, route=route), golden,
+                  f"model {prog.model.name!r} (route={route!r})")
+
+
+def assert_golden(got, golden, what: str) -> None:
+    """Raise :class:`GoldenMismatch` naming every field of the record
+    ``got`` that differs from ``golden``."""
     bad = []
     for key in ("e_input", "acc_crc"):
         if int(got[key]) != int(golden[key]):
@@ -123,9 +140,8 @@ def check_golden(prog: EngineProgram, golden, frames=None, *,
             bad.append(f"{key}: got {np.asarray(got[key]).tolist()}, "
                        f"golden {np.asarray(golden[key]).tolist()}")
     if bad:
-        raise GoldenMismatch(
-            f"model {prog.model.name!r} (route={route!r}) diverged from "
-            f"its golden: " + "; ".join(bad))
+        raise GoldenMismatch(f"{what} diverged from its golden: "
+                             + "; ".join(bad))
 
 
 def save_golden(path, golden) -> None:

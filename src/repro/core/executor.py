@@ -120,7 +120,7 @@ class EngineExecutor:
     """
 
     def __init__(self, program: EngineProgram, *, batch_size: int = 32,
-                 route: str | None = None, interpret: bool | None = None,
+                 route: str | None = None,
                  donate: bool | None = None, output: str = "top1",
                  max_inflight: int = DEFAULT_MAX_INFLIGHT,
                  on_result: Callable[[object, np.ndarray], None] | None = None):
@@ -134,7 +134,7 @@ class EngineExecutor:
         # submit_batch / flush_inflight, so the callback is never fired.
         self.on_error: Callable[[object, BaseException], None] | None = None
         self.runner: CompiledRunner = program.compile_runner(
-            route=route, interpret=interpret, donate=donate)
+            route=route, donate=donate)
         self.stats = ServeStats()
         self.stats._first_n = self.batch_size
         # One lock serializes the pending micro-batch, the in-flight

@@ -38,8 +38,6 @@ from repro.configs.base import ModelConfig
 from repro.models import layers as L
 from repro.models import recurrent as R
 
-from repro.compat import shard_map
-
 Params = dict[str, Any]
 
 SUPPORTED_UNIT_KINDS = ("attn", "attn_local", "moe", "mla", "mla_moe",
@@ -54,7 +52,8 @@ def make_pipeline_mesh(n_data: int, n_stage: int, n_tp: int,
         (n_data, n_stage, n_tp)
     axes = (("pod", "data", "stage", "tp") if n_pod > 1 else
             ("data", "stage", "tp"))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # ---------------------------------------------------------------------------
@@ -239,7 +238,7 @@ def pipeline_body_fn(ctx: PipelineContext, mesh: Mesh, units_shape):
     pos_ndim = 3 if cfg.mrope else 2
     unit_specs = _unit_specs(cfg, T, units_shape)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(unit_specs, P("stage", None),
                        P(batch_axes, None, None), P(batch_axes, None)
                        if pos_ndim == 2 else P(batch_axes, None, None)),

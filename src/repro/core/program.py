@@ -26,12 +26,18 @@ The paper's central object is a *balanced plan*: per-layer workloads
 oracle — the two are bit-identical, which is what ``tests/test_program.py``
 pins down.
 
+Calibration and quantization run on the host CPU device
+(:func:`host_device`), so the compiled integers are the same whichever
+accelerator later runs the MACs, and the goldens in ``tests/golden/``
+hold for every platform.
+
 For serving, :meth:`EngineProgram.compile_runner` lowers the *whole* step
 chain into one ``jax.jit``-compiled function (weights, bias and shift
-schedules captured as constants, the int8 activation buffer donated), so a
-stream of frames runs as a single fused device program instead of the
-eager per-step loop — the software analogue of switching the paper's
-engines from frame-at-a-time operation to the steady-state pipeline.
+schedules passed as arguments that live on the runner's device, the int8
+activation buffer donated), so a stream of frames runs as a single fused
+device program instead of the eager per-step loop — the software analogue
+of switching the paper's engines from frame-at-a-time operation to the
+steady-state pipeline.
 """
 
 from __future__ import annotations
@@ -55,6 +61,14 @@ DEFAULT_THETA = 900
 DEFAULT_BRAM = 1090
 DEFAULT_BW = 4.2e9
 DEFAULT_FREQ = 200e6
+
+
+def host_device():
+    """The host CPU device. Parameter draws, calibration and quantization
+    run here: an accelerator's float arithmetic (bf16 matmul passes,
+    different transcendental code) could move a rounding boundary and
+    change the compiled integers."""
+    return jax.devices("cpu")[0]
 
 
 # ---------------------------------------------------------------------------
@@ -119,10 +133,11 @@ class EngineStep:
     kind: str                      # "conv" | "fc" | "pool"
     layer: ConvLayer
     pad: tuple[int, int]           # (lo, hi), both spatial dims
-    # compute-step payload (None for pool):
-    wq: jnp.ndarray | None = None          # int8/int16 quantized weights
-    bias_q: jnp.ndarray | None = None      # int32 bias on the acc format
-    shift: jnp.ndarray | None = None       # int32 [M]: e_out - (e_in+e_w)
+    # compute-step payload (None for pool), host numpy arrays; a runner
+    # puts them on its device once:
+    wq: np.ndarray | None = None           # int8/int16 quantized weights
+    bias_q: np.ndarray | None = None       # int32 bias on the acc format
+    shift: np.ndarray | None = None        # int32 [M]: e_out - (e_in+e_w)
     e_in: int = 0                          # input activation exponent
     e_w: np.ndarray | None = None          # int [M] weight exponents
     e_out: int = 0                         # output activation exponent
@@ -166,8 +181,7 @@ class EngineProgram:
         last = [s for s in self.steps if s.kind != "pool"][-1]
         return np.exp2(np.asarray(last.e_in + last.e_w, np.float32))
 
-    def run(self, x: jnp.ndarray, *, use_kernel: bool = False,
-            interpret: bool | None = None) -> jnp.ndarray:
+    def run(self, x: jnp.ndarray, *, use_kernel: bool = False) -> jnp.ndarray:
         """Fixed-point forward, eagerly step by step. ``x`` is float NHWC;
         returns float logits (the final engine's 32-bit accumulators on
         their exact po2 scale). All intermediate activations are int8
@@ -176,10 +190,9 @@ class EngineProgram:
         if self.steps is None:
             raise ValueError(
                 "plan-only program (compiled without params) cannot run")
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
         if use_kernel:
             require_kernel(self.bits)
+            interpret = _kernel_interpret(jax.devices()[0])
         xq = quant.quantize_to_exponent(x, self.e_input, self.bits)
         for step in self.steps:
             if step.kind == "pool":
@@ -223,7 +236,6 @@ class EngineProgram:
         return route
 
     def compile_runner(self, *, route: str | None = None,
-                       interpret: bool | None = None,
                        donate: bool | None = None) -> "CompiledRunner":
         """Lower the whole step chain into ONE jitted function over a batch
         of already-quantized frames and wrap it as a :class:`CompiledRunner`.
@@ -241,8 +253,8 @@ class EngineProgram:
           integer conv), ~10x over the int32 oracle on CPU.
         * ``"oracle"`` — the pure-jnp int32 oracle (default for bits=16,
           whose 48-bit accumulator model is already float).
-        * ``"kernel"`` — the Pallas PE-array kernel (interpret mode off
-          TPU). Availability is checked here, once, not per step.
+        * ``"kernel"`` — the Pallas PE-array kernel (interpret mode on a
+          CPU device). Availability is checked here, once, not per step.
 
         ``donate`` donates the int8 activation buffer to the call so XLA
         reuses it for intermediates instead of round-tripping fresh
@@ -252,11 +264,10 @@ class EngineProgram:
             raise ValueError(
                 "plan-only program (compiled without params) cannot run")
         return self.compile_stage_runner(0, len(self.steps), route=route,
-                                         interpret=interpret, donate=donate)
+                                         donate=donate)
 
     def compile_stage_runner(self, start: int, stop: int, *,
                              route: str | None = None,
-                             interpret: bool | None = None,
                              donate: bool | None = None,
                              device=None) -> "CompiledRunner":
         """Jit the contiguous step range ``[start, stop)`` as one device
@@ -268,14 +279,16 @@ class EngineProgram:
         ``tests/test_serving.py``). ``compile_runner`` itself is the
         degenerate single-stage case ``[0, len(steps))``.
 
-        ``device`` pins the stage to one ``jax.Device``: inputs are
-        ``jax.device_put`` onto it before dispatch, so the jit traces,
-        compiles, and runs there (weights, captured as constants, follow).
-        This is how the serving pipeline places each stage on its own
-        device — the software analogue of each paper engine owning its
-        own DSP/BRAM partition. Placement never changes the integers:
-        every route is bit-exact on any backend, so placed output ==
-        unplaced output (pinned by ``tests/test_serving.py``)."""
+        ``device`` pins the stage to one ``jax.Device``: the stage's
+        weights are put there once, and inputs are ``jax.device_put``
+        onto it before dispatch, so the jit compiles and runs there. This
+        is how the serving pipeline places each stage on its own device —
+        the software analogue of each paper engine owning its own
+        DSP/BRAM partition. Without a pin the stage uses the default
+        device. The kernel route runs the Pallas kernel in interpret mode
+        only on a CPU device. Placement never changes the integers: every
+        route is bit-exact on any backend, so placed output == unplaced
+        output (pinned by ``tests/test_serving.py``)."""
         if self.steps is None:
             raise ValueError(
                 "plan-only program (compiled without params) cannot run")
@@ -285,17 +298,23 @@ class EngineProgram:
                 f"{len(self.steps)}-step chain")
         steps = tuple(self.steps[start:stop])
         route = self._resolve_route(route, steps)
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
+        target = device if device is not None else jax.devices()[0]
+        interpret = (_kernel_interpret(target) if route == "kernel"
+                     else False)
         if donate is None:
-            donate = jax.devices()[0].platform != "cpu"
+            donate = target.platform != "cpu"
         bits = self.bits
+        weights = jax.device_put(
+            {s.name: {"wq": s.wq, "bias_q": s.bias_q, "shift": s.shift}
+             for s in steps if s.kind != "pool"}, device)
 
-        def chain(xq: jnp.ndarray) -> jnp.ndarray:
+        def chain(xq: jnp.ndarray, weights: dict) -> jnp.ndarray:
             for step in steps:
                 if step.kind == "pool":
                     xq = _pool_int(xq, step)
-                elif route == "kernel":
+                    continue
+                step = dataclasses.replace(step, **weights[step.name])
+                if route == "kernel":
                     xq = _step_kernel(xq, step, interpret)
                 elif route == "f32":
                     xq = _step_exact_f32(xq, step)
@@ -305,7 +324,8 @@ class EngineProgram:
 
         fn = jax.jit(chain, donate_argnums=(0,) if donate else ())
         return CompiledRunner(program=self, route=route, donate=donate,
-                              fn=fn, start=start, stop=stop, device=device)
+                              fn=fn, weights=weights, start=start,
+                              stop=stop, device=device)
 
 
 @dataclasses.dataclass
@@ -315,20 +335,24 @@ class CompiledRunner:
     (``start == 0``, ``stop == len(steps)``), or one pipeline stage for
     :meth:`EngineProgram.compile_stage_runner`.
 
-    ``fn`` maps an int8 (int16 for bits=16) activation batch
+    ``fn(xq, weights)`` maps an int8 (int16 for bits=16) activation batch
     ``[B, H, W, C]`` to the range's output — raw final accumulators when
-    the range includes the last engine, int8 activations otherwise —
-    with weights/bias/shift schedules captured as constants, so a fixed
-    batch shape compiles exactly once (``cache_size`` is the recompile
-    guard the tests pin). Host-side quantize-in and argmax/dequant-out
-    live here so the executor can overlap them with device compute; they
-    exist only at the matching end of the chain (first / last stage).
+    the range includes the last engine, int8 activations otherwise.
+    ``weights`` holds the range's weight/bias/shift schedules, put on the
+    runner's device once; passing them as arguments rather than jit
+    constants keeps the compiled program free of ~100 MB of embedded
+    weights. A fixed batch shape compiles exactly once (``cache_size`` is
+    the recompile guard the tests pin). Host-side quantize-in and
+    argmax/dequant-out live here so the executor can overlap them with
+    device compute; they exist only at the matching end of the chain
+    (first / last stage).
     """
 
     program: EngineProgram
     route: str
     donate: bool
-    fn: Callable[[jnp.ndarray], jnp.ndarray]
+    fn: Callable[[jnp.ndarray, dict], jnp.ndarray]
+    weights: dict
     start: int = 0
     stop: int = -1          # -1 == len(program.steps) (whole chain)
     device: object = None   # jax.Device pin (None = backend default)
@@ -375,7 +399,7 @@ class CompiledRunner:
             xq = jnp.array(xq, copy=True)
         if self.device is not None:
             xq = jax.device_put(xq, self.device)
-        return self.fn(jnp.asarray(xq))
+        return self.fn(jnp.asarray(xq), self.weights)
 
     def dequantize(self, acc) -> np.ndarray:
         """Raw final accumulators -> float32 logits on their exact po2
@@ -402,11 +426,8 @@ class CompiledRunner:
 
     def cache_size(self) -> int:
         """Number of distinct XLA executables behind ``fn`` (recompile
-        guard: one batch shape must stay at 1). Reads a private JAX API;
-        returns -1 ("unknown") on jax versions that don't expose it
-        rather than breaking the serve path."""
-        probe = getattr(self.fn, "_cache_size", None)
-        return int(probe()) if callable(probe) else -1
+        guard: one batch shape must stay at 1)."""
+        return int(self.fn._cache_size())
 
 
 def kernel_available(bits: int = 8) -> tuple[bool, str]:
@@ -419,6 +440,18 @@ def kernel_available(bits: int = 8) -> tuple[bool, str]:
     except Exception as e:  # pragma: no cover - depends on install
         return False, f"Pallas conv2d_int8 kernel unavailable: {e!r}"
     return True, ""
+
+
+def _kernel_interpret(device) -> bool:
+    """Whether the Pallas kernel runs in interpret mode on ``device``:
+    compiled on a TPU, interpreted on a CPU, refused anywhere else."""
+    if device.platform == "tpu":
+        return False
+    if device.platform == "cpu":
+        return True
+    raise NotImplementedError(
+        f"the Pallas int8 kernel runs on TPU (or interpreted on CPU), "
+        f"not on {device.platform}")
 
 
 def require_kernel(bits: int = 8) -> None:
@@ -593,6 +626,7 @@ def compile_model(model: CNNModel, params: Params | None = None, *,
     only, all K=1). ``bram_weights=True`` makes Algorithm 2 charge weight
     buffers against the BRAM budget and pin hot weight sets on-chip (the
     Table I BRAM-column model; plan-only analytics, never the arithmetic).
+    Calibration and quantization run on :func:`host_device`.
     """
     workloads = model.layer_workloads(weight_bits=bits)
     allocs = allocate_compute(workloads, theta, objective=objective)
@@ -609,9 +643,13 @@ def compile_model(model: CNNModel, params: Params | None = None, *,
         raise ValueError("compiling an executable program needs a "
                          "calib_batch to freeze activation formats")
     amax: dict[str, float] = {}
-    float_forward(params, model, calib_batch, record=amax)
-    prog.e_input = quant.po2_exponent(amax["__input__"], bits)
-    prog.steps = _lower(model, params, amax, prog.e_input, bits)
+    host = host_device()
+    with jax.default_device(host):
+        params = jax.device_put(params, host)
+        float_forward(params, model, jax.device_put(calib_batch, host),
+                      record=amax)
+        prog.e_input = quant.po2_exponent(amax["__input__"], bits)
+        prog.steps = _lower(model, params, amax, prog.e_input, bits)
     return prog
 
 
@@ -661,8 +699,8 @@ def _lower(model: CNNModel, params: Params, amax: dict[str, float],
         shift = np.clip(e_out - acc_e, -31, 31).astype(np.int32)
         steps.append(EngineStep(
             name=lyr.name, kind=lyr.kind, layer=lyr, pad=pad,
-            wq=jnp.asarray(wq), bias_q=jnp.asarray(bias_q),
-            shift=jnp.asarray(shift), e_in=e_act, e_w=e_w, e_out=e_out,
+            wq=np.asarray(wq), bias_q=bias_q, shift=shift,
+            e_in=e_act, e_w=e_w, e_out=e_out,
             relu=not is_last, requantize=not is_last))
         e_act = e_out
         hw = lyr.out_hw(hw)

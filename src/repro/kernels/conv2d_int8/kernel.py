@@ -34,23 +34,24 @@ def _kernel(x_ref, w_ref, bias_ref, shift_ref, o_ref, acc_ref, *, n_k: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = x_ref[...].astype(jnp.int32)          # [bn, bk]
-    b = w_ref[...].astype(jnp.int32)          # [bk, bm]
+    # int8 tiles straight into the MXU's int8 path, int32 accumulate
+    # (the MXU has no int32 x int32 matmul).
     acc_ref[...] += jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         # The paper's output stage, fused: 32-bit partial sums + bias, ReLU,
         # per-output-channel shift onto the activation format, truncate.
-        acc = acc_ref[...] + bias_ref[...].astype(jnp.int32)[None, :]
+        acc = acc_ref[...] + bias_ref[...]            # [1, bm] broadcast
         if relu:
             acc = jnp.maximum(acc, 0)
         if emit_int32:
             # Raw 32-bit partial sums (the psumSpad view, pre-requantize).
             o_ref[...] = acc
         else:
-            sh = shift_ref[...].astype(jnp.int32)[None, :]  # [1, bm]
+            sh = shift_ref[...]                          # [1, bm]
             # shift >= 0: right-shift + truncate; shift < 0: the left-shift
             # branch of the Fig. 3(c) aligner (output format finer than the
             # accumulator's), saturating instead of wrapping int32.
@@ -79,11 +80,13 @@ def gemm_int8(x: jnp.ndarray, w: jnp.ndarray, shift: jnp.ndarray,
     if bias is None:
         bias = jnp.zeros((M,), jnp.int32)
     bn_, bm_, bk_ = min(bn, _rnd(N)), min(bm, _rnd(M)), min(bk, _rnd(K))
-    Np, Mp, Kp = _pad(N, bn_), _pad(M, bm_), _pad(K, bk_)
+    Np, Mp, Kp = _pad(N, bn_), _pad(M, bm_), padded_k(K, bk)
     xp = jnp.pad(x, ((0, Np - N), (0, Kp - K)))
     wp = jnp.pad(w, ((0, Kp - K), (0, Mp - M)))
-    bp = jnp.pad(bias.astype(jnp.int32), (0, Mp - M))
-    sp = jnp.pad(shift.astype(jnp.int32), (0, Mp - M))
+    # Bias and shift ride as [1, Mp] rows: a 1-D block's lane tiling
+    # does not match Mosaic's layout for it.
+    bp = jnp.pad(bias.astype(jnp.int32), (0, Mp - M)).reshape(1, Mp)
+    sp = jnp.pad(shift.astype(jnp.int32), (0, Mp - M)).reshape(1, Mp)
     n_k = Kp // bk_
     grid = (Np // bn_, Mp // bm_, n_k)
     out_dt = jnp.int32 if emit_int32 else jnp.int8
@@ -94,8 +97,8 @@ def gemm_int8(x: jnp.ndarray, w: jnp.ndarray, shift: jnp.ndarray,
         in_specs=[
             pl.BlockSpec((bn_, bk_), lambda n, m, k: (n, k)),
             pl.BlockSpec((bk_, bm_), lambda n, m, k: (k, m)),
-            pl.BlockSpec((bm_,), lambda n, m, k: (m,)),
-            pl.BlockSpec((bm_,), lambda n, m, k: (m,)),
+            pl.BlockSpec((1, bm_), lambda n, m, k: (0, m)),
+            pl.BlockSpec((1, bm_), lambda n, m, k: (0, m)),
         ],
         out_specs=pl.BlockSpec((bn_, bm_), lambda n, m, k: (n, m)),
         out_shape=jax.ShapeDtypeStruct((Np, Mp), out_dt),
@@ -103,6 +106,11 @@ def gemm_int8(x: jnp.ndarray, w: jnp.ndarray, shift: jnp.ndarray,
         interpret=interpret,
     )(xp, wp, bp, sp)
     return out[:N, :M]
+
+
+def padded_k(K: int, bk: int = 256) -> int:
+    """The reduction length :func:`gemm_int8` pads ``K`` to."""
+    return _pad(K, min(bk, _rnd(K)))
 
 
 def _rnd(n: int, to: int = 128) -> int:

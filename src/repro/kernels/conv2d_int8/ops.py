@@ -15,7 +15,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.conv2d_int8.kernel import gemm_int8
+from repro.kernels.conv2d_int8.kernel import gemm_int8, padded_k
 from repro.kernels.conv2d_int8.ref import conv2d_int8_via
 
 
@@ -36,7 +36,8 @@ def conv2d_int8(x: jnp.ndarray, w: jnp.ndarray, shift: jnp.ndarray,
     """
     return conv2d_int8_via(gemm_int8, x, w, shift, bias, stride=stride,
                            padding=padding, groups=groups, relu=relu,
-                           interpret=interpret, emit_int32=emit_int32)
+                           pad_k=padded_k, interpret=interpret,
+                           emit_int32=emit_int32)
 
 
 @partial(jax.jit, static_argnames=("relu", "interpret", "emit_int32"))

@@ -49,11 +49,12 @@ def same_padding(in_hw: int, kernel: int, stride: int) -> tuple[int, int]:
 
 
 def im2col_int8(x: jnp.ndarray, R: int, S: int, stride: int,
-                pad: Pad2) -> jnp.ndarray:
+                pad: Pad2, k_total: int | None = None) -> jnp.ndarray:
     """int8 im2col with no float materialization: x [B,H,W,C] ->
     [B,Ho,Wo,R*S*C], features ordered (r, s, c). ``pad`` is
     ((top, bottom), (left, right)); zero-padding is exact for the
-    symmetric (zero-point-0) po2 formats."""
+    symmetric (zero-point-0) po2 formats. ``k_total`` appends zero
+    feature columns up to that length."""
     xp = jnp.pad(x, ((0, 0), pad[0], pad[1], (0, 0)))
     Hp, Wp = xp.shape[1], xp.shape[2]
     Ho = (Hp - R) // stride + 1
@@ -61,6 +62,9 @@ def im2col_int8(x: jnp.ndarray, R: int, S: int, stride: int,
     cols = [xp[:, r:r + (Ho - 1) * stride + 1:stride,
                s:s + (Wo - 1) * stride + 1:stride, :]
             for r in range(R) for s in range(S)]
+    k = R * S * x.shape[-1]
+    if k_total is not None and k_total > k:
+        cols.append(jnp.zeros(cols[0].shape[:3] + (k_total - k,), x.dtype))
     return jnp.concatenate(cols, axis=-1)
 
 
@@ -74,24 +78,33 @@ def _resolve_pad(padding, in_h: int, in_w: int, R: int, S: int,
 def conv2d_int8_via(gemm_fn, x: jnp.ndarray, w: jnp.ndarray,
                     shift: jnp.ndarray, bias: jnp.ndarray | None = None, *,
                     stride: int = 1, padding="same", groups: int = 1,
-                    relu: bool = False, **gemm_kwargs) -> jnp.ndarray:
+                    relu: bool = False, pad_k=None,
+                    **gemm_kwargs) -> jnp.ndarray:
     """Conv as implicit GEMM over any engine: one weight-stationary
     ``gemm_fn(patches, w2d, shift, bias, relu=..., **gemm_kwargs)`` per
     channel group. Shared by the jnp oracle and the Pallas route so the
-    spatial plumbing (stride, asymmetric padding, groups) cannot drift."""
+    spatial plumbing (stride, asymmetric padding, groups) cannot drift.
+
+    ``pad_k(K)`` is the reduction length ``gemm_fn`` pads to. The zero
+    columns are then built into the patches here: padding the flattened
+    patch matrix instead makes the TPU compiler take minutes on the
+    large-stride stems (AlexNet/ZF conv1 at batch 8)."""
     R, S, Cg, M = w.shape
     B, H, W, C = x.shape
     assert C == Cg * groups and M % groups == 0, (x.shape, w.shape, groups)
     pad = _resolve_pad(padding, H, W, R, S, stride)
     outs = []
     Mg = M // groups
+    K = R * S * Cg
+    Kp = K if pad_k is None else pad_k(K)
     for g in range(groups):
         xg = x[..., g * Cg:(g + 1) * Cg]
-        patches = im2col_int8(xg, R, S, stride, pad)
-        Bp, Ho, Wo, K = patches.shape
-        wg = w[..., g * Mg:(g + 1) * Mg].reshape(R * S * Cg, Mg)
+        patches = im2col_int8(xg, R, S, stride, pad, k_total=Kp)
+        _, Ho, Wo, _ = patches.shape
+        wg = jnp.pad(w[..., g * Mg:(g + 1) * Mg].reshape(K, Mg),
+                     ((0, Kp - K), (0, 0)))
         bg = None if bias is None else bias[g * Mg:(g + 1) * Mg]
-        out = gemm_fn(patches.reshape(-1, K), wg,
+        out = gemm_fn(patches.reshape(-1, Kp), wg,
                       shift[g * Mg:(g + 1) * Mg], bg, relu=relu,
                       **gemm_kwargs)
         outs.append(out.reshape(B, Ho, Wo, Mg))

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(a_ref, b_ref, o_ref, h_ref, *, chunk: int):
     c = pl.program_id(1)
@@ -67,7 +65,7 @@ def linear_scan(a: jnp.ndarray, b: jnp.ndarray, *, chunk: int = 256,
         out_specs=pl.BlockSpec((bt, chunk, D), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), b.dtype),
         scratch_shapes=[pltpu.VMEM((bt, D), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a.astype(jnp.float32), b.astype(jnp.float32))

@@ -24,7 +24,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import ARCHS, get as get_arch
 from repro.launch.mesh import make_production_mesh
 from repro.launch.shapes import (SHAPES, cache_specs, cell_is_runnable,
@@ -49,7 +48,7 @@ def run_cell(arch: str, shape: str, mesh_name: str, dist: str = "pjit",
                 "status": "skipped", "reason": why}
     mesh = _mesh_for(mesh_name)
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if dist == "pipeline":
             res = _run_pipeline_cell(cfg, case, mesh, mesh_name, stages)
         else:
@@ -229,7 +228,7 @@ def _run_pipeline_cell(cfg, case, mesh, mesh_name, stages: int) -> dict:
             case.global_batch // (16 * n_pod))
     K = max(K, 1)
     ctx = PL.PipelineContext(cfg=cfg, unit_kind=kind, S=S, T=T, n_micro=K)
-    with compat.set_mesh(pmesh):
+    with jax.set_mesh(pmesh):
         batch_sds = input_specs(cfg, case)
         if train:
             loss_fn = PL.pipeline_loss_fn(ctx, pmesh, units_shape,

@@ -14,7 +14,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (data, model); two pods = (2, 16, 16) over (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def device_slices(n_slices: int, devices=None) -> list[list]:
@@ -44,6 +44,12 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2, n_pod: int = 1):
     """Small host-device mesh for tests (requires
     XLA_FLAGS=--xla_force_host_platform_device_count>=n_data*n_model*n_pod)."""
     if n_pod > 1:
-        return jax.make_mesh((n_pod, n_data, n_model),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((n_pod, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto mode: the sharding rules
+    (``repro.runtime.sharding``) leave propagation to the compiler."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

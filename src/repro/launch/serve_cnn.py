@@ -47,6 +47,7 @@ from __future__ import annotations
 import argparse
 
 from repro.core import workload as W
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.server import (compile_for_serving, serve, serve_async,
                                   serve_knee, serve_knee_rescale,
                                   serve_qos, synthetic_stream)
@@ -59,6 +60,7 @@ __all__ = ["compile_for_serving", "synthetic_stream", "serve",
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--model", default="alexnet",
                     choices=sorted(W.CNN_MODELS))

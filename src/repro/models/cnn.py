@@ -21,13 +21,20 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core.program import compile_model, float_forward
+from repro.core.program import compile_model, float_forward, host_device
 from repro.core.workload import CNNModel
 
 Params = dict[str, Any]
 
 
 def init_params(model: CNNModel, key=None, dtype=jnp.float32) -> Params:
+    """Seeded random weights, drawn on the host CPU device so the same
+    key gives the same floats on every platform."""
+    with jax.default_device(host_device()):
+        return _init_params(model, key, dtype)
+
+
+def _init_params(model: CNNModel, key, dtype) -> Params:
     key = key if key is not None else jax.random.PRNGKey(0)
     p: Params = {}
     hw = model.input_hw

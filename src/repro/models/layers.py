@@ -522,9 +522,8 @@ def moe_apply(p: Params, cfg, x):
     tensor of global-token extent would not fit at 1M tokens x 256
     experts). Without a mesh (single-device smoke tests) the same math runs
     locally."""
-    from repro.compat import ambient_mesh
-    mesh = ambient_mesh()
-    if mesh is not None and "model" in mesh.axis_names \
+    mesh = jax.sharding.get_abstract_mesh()
+    if "model" in mesh.axis_names \
             and mesh.axis_sizes and math.prod(mesh.axis_sizes) > 1:
         return _moe_sharded(p, cfg, x, mesh)
     return _moe_local(p, cfg, x)
@@ -546,8 +545,7 @@ def _moe_sharded(p: Params, cfg, x, mesh):
     if "shared" in p:
         espec["shared"] = jax.tree.map(lambda _: P(), p["shared"])
 
-    from repro.compat import shard_map
-    @partial(shard_map, mesh=mesh, in_specs=(espec, bspec),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(espec, bspec),
              out_specs=(bspec, P()), check_vma=False)
     def run(p_loc, x_loc):
         y, aux = _moe_expert_parallel(p_loc, cfg, x_loc, axis="model",

@@ -64,7 +64,7 @@ class PipelineExecutor:
 
     def __init__(self, program: EngineProgram, *, stages: int = 2,
                  batch_size: int = 32, boundaries: Sequence[int] | None = None,
-                 route: str | None = None, interpret: bool | None = None,
+                 route: str | None = None,
                  donate: bool | None = None, output: str = "top1",
                  queue_depth: int = DEFAULT_QUEUE_DEPTH,
                  place_stages: bool = False,
@@ -102,8 +102,7 @@ class PipelineExecutor:
         else:
             self.stage_devices = [None] * self.partition.n_stages
         self.runners: list[CompiledRunner] = [
-            program.compile_stage_runner(b, e, route=route,
-                                         interpret=interpret, donate=donate,
+            program.compile_stage_runner(b, e, route=route, donate=donate,
                                          device=dev)
             for (b, e), dev in zip(self.partition.stage_ranges(),
                                    self.stage_devices)]

@@ -98,7 +98,7 @@ class ReplicaPool:
                  executors: Sequence[object] | None = None,
                  replicas: int = 2, mode: str = "pipeline",
                  stages: int = 2, batch_size: int = 32,
-                 route: str | None = None, interpret: bool | None = None,
+                 route: str | None = None,
                  donate: bool | None = None, output: str = "top1",
                  queue_depth: int = DEFAULT_QUEUE_DEPTH,
                  devices: Sequence[object] | None = None,
@@ -133,8 +133,8 @@ class ReplicaPool:
             self.batch_size = int(batch_size)
             self.replicas, self.replica_devices = self._build_replicas(
                 program, replicas, mode, stages=stages, batch_size=batch_size,
-                route=route, interpret=interpret, donate=donate,
-                output=output, queue_depth=queue_depth, devices=devices)
+                route=route, donate=donate, output=output,
+                queue_depth=queue_depth, devices=devices)
         self.n_replicas = len(self.replicas)
         self.partition = getattr(self.replicas[0], "partition", None)
         self.route = getattr(self.replicas[0], "route", route)
@@ -173,7 +173,7 @@ class ReplicaPool:
 
     @staticmethod
     def _build_replicas(program, replicas, mode, *, stages, batch_size,
-                        route, interpret, donate, output, queue_depth,
+                        route, donate, output, queue_depth,
                         devices):
         import jax  # deferred: fake-executor pools never touch devices
 
@@ -194,8 +194,8 @@ class ReplicaPool:
             n_stages = stages if mode == "pipeline" else max(1, len(sl))
             built.append(PipelineExecutor(
                 program, stages=n_stages, batch_size=batch_size,
-                route=route, interpret=interpret, donate=donate,
-                output=output, queue_depth=queue_depth, devices=sl))
+                route=route, donate=donate, output=output,
+                queue_depth=queue_depth, devices=sl))
             built_devs.append([str(d) for d in sl])
         return built, built_devs
 
@@ -327,11 +327,15 @@ class ReplicaPool:
     def warmup(self, frames: Iterable[np.ndarray]) -> None:
         """Run one drained pass through *every* replica directly (all
         R x K stage jits compile), bypassing the router so no replica is
-        left cold. Follow with :meth:`reset_stats` for a hot measured
-        window."""
+        left cold. Every replica is fed before any is drained, so the
+        replicas compile concurrently (one executable per device). Follow
+        with :meth:`reset_stats` for a hot measured window."""
         frames = list(frames)
         for rep in self.replicas:
-            rep.serve(frames)
+            for f in frames:
+                rep.submit(f)
+        for rep in self.replicas:
+            rep.drain()
 
     def flush_inflight(self) -> None:
         """Protocol no-op: every replica's collector thread delivers

@@ -47,8 +47,6 @@ import numpy as np
 
 from repro.core import workload as W
 from repro.core.executor import EngineExecutor
-from repro.core.program import compile_model
-from repro.models import cnn
 from repro.serving.calibrate import (default_max_wait_ms,
                                      pipeline_throughput, warmed_frontend)
 from repro.serving.estimator import ServiceTimeEstimator, window_key
@@ -78,19 +76,11 @@ def compile_for_serving(model_name: str, *, bits: int = 8, seed: int = 0,
     """Compile ``model_name`` exactly as the serve paths consume it:
     seeded params, seeded calibration batch, Table I's budget convention
     for the bit width (the plan only affects modeled numbers — never the
-    executed arithmetic)."""
-    m = W.CNN_MODELS[model_name]()
-    params = cnn.init_params(m, jax.random.PRNGKey(seed))
-    calib = jax.random.normal(
-        jax.random.PRNGKey(seed + 1), (1, m.input_hw, m.input_hw,
-                                       m.input_ch))
-    # 8-bit double-pumps the 900 DSPs, so modeled_fps_alg1 here equals
-    # the fps8/fps16 column in benchmarks/table1.py.
-    if theta is None:
-        theta = 2 * 900 - len(m.layers) if bits == 8 else 900
-    kwargs = {"theta": theta,
-              "bram_total": None if bits == 8 else 545}
-    return compile_model(m, params, bits=bits, calib_batch=calib, **kwargs)
+    executed arithmetic). The compiler front door's
+    :func:`repro.compiler.quantize` is that convention."""
+    from repro.compiler import quantize
+    return quantize(W.CNN_MODELS[model_name](), bits=bits, seed=seed,
+                    theta=theta)
 
 
 def synthetic_stream_like(model, frames: int, seed: int = 0) -> np.ndarray:
@@ -944,7 +934,7 @@ def serve(model_name: str, *, frames: int = 64, batch: int = 16,
     st = ex.stats
 
     # cache_size() counts XLA executables (1 = compiled once, never
-    # recompiled); -1 means the running jax doesn't expose the counter.
+    # recompiled).
     n_exec = ex.runner.cache_size()
     result = {
         "model": model_name,
@@ -958,7 +948,7 @@ def serve(model_name: str, *, frames: int = 64, batch: int = 16,
         "measured_steady_fps": round(st.steady_fps, 3),
         "modeled_fps_alg1": round(prog.fps(), 3),
         "executables": n_exec,
-        "recompiles": (n_exec - 1) if n_exec >= 0 else None,
+        "recompiles": n_exec - 1,
         "sample_top1": [int(np.asarray(o).reshape(-1).argmax())
                         if output == "logits" else int(o)
                         for o in outs[:4]],

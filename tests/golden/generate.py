@@ -2,10 +2,13 @@
 
   PYTHONPATH=src python tests/golden/generate.py [model ...]
 
-One ``<model>.npz`` per model, produced by the jitted batched runner
-(route="f32" — bit-identical to the int32 oracle and the Pallas kernel)
-on deterministic params/frames (``cnn.init_params`` uses a crc32 layer
-fold, so the draw reproduces exactly across runs and machines). Stored:
+One ``<model>.npz`` per model: :func:`repro.compiler.make_golden` over
+the program the serve paths compile (``compile_for_serving``: seeded
+params and calibration, both drawn and run on the host CPU device), run
+on the jitted batched runner (route="f32" — bit-identical to the int32
+oracle and the Pallas kernel) over the seeded golden frames. Because
+the compile is host-side, the same record is the ground truth on every
+platform; ``chip_smoke.py`` checks it on the TPU. Stored:
 
   acc_sample  first 32 raw int32 accumulators of frame 0
   acc_crc     crc32 of the full int32 accumulator buffer (both frames)
@@ -20,44 +23,17 @@ semantics change *intentionally* — and say so in the commit.
 
 import os
 import sys
-import zlib
 
-import jax
-import numpy as np
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 
-from repro.core import workload as W                     # noqa: E402
-from repro.core.program import compile_model             # noqa: E402
-from repro.models import cnn                             # noqa: E402
+from repro.compiler import make_golden, save_golden        # noqa: E402
+from repro.serving.server import compile_for_serving       # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-N_FRAMES = 2
-N_SAMPLE = 32
 
 
 def golden_for(model_name: str) -> dict:
-    m = W.CNN_MODELS[model_name]()
-    params = cnn.init_params(m, jax.random.PRNGKey(0))
-    calib = jax.random.normal(jax.random.PRNGKey(1),
-                              (1, m.input_hw, m.input_hw, m.input_ch))
-    prog = compile_model(m, params, bits=8, calib_batch=calib)
-    frames = np.asarray(jax.random.normal(
-        jax.random.PRNGKey(2), (N_FRAMES, m.input_hw, m.input_hw,
-                                m.input_ch)), np.float32)
-    runner = prog.compile_runner(route="f32")
-    acc = np.asarray(runner(runner.quantize(frames)))
-    assert acc.dtype == np.int32, acc.dtype
-    logits = runner.dequantize(acc)
-    return {
-        "acc_sample": acc[0].reshape(-1)[:N_SAMPLE].astype(np.int32),
-        "acc_crc": np.int64(zlib.crc32(np.ascontiguousarray(acc).tobytes())),
-        "top1": np.argmax(logits.reshape(N_FRAMES, -1), -1).astype(np.int64),
-        "e_input": np.int64(prog.e_input),
-        "e_out": np.asarray([s.e_out for s in prog.steps
-                             if s.kind != "pool"], np.int64),
-    }
+    return make_golden(compile_for_serving(model_name), route="f32")
 
 
 def main(argv=None) -> int:
@@ -65,7 +41,7 @@ def main(argv=None) -> int:
     for name in models:
         data = golden_for(name)
         out = os.path.join(HERE, f"{name}.npz")
-        np.savez(out, **data)
+        save_golden(out, data)
         print(f"wrote {out}: top1={data['top1'].tolist()} "
               f"crc={int(data['acc_crc'])} e_input={int(data['e_input'])}")
     return 0

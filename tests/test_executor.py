@@ -193,14 +193,10 @@ def test_golden_int8_program(model):
     np.testing.assert_array_equal(got["top1"], want["top1"])
     assert int(got["acc_crc"]) == int(want["acc_crc"])
     # and the jitted batched path == the eager oracle on the same program
-    m = W.CNN_MODELS[model]()
-    p = cnn.init_params(m, jax.random.PRNGKey(0))
-    calib = jax.random.normal(jax.random.PRNGKey(1),
-                              (1, m.input_hw, m.input_hw, m.input_ch))
-    prog = compile_model(m, p, bits=8, calib_batch=calib)
-    frame = np.asarray(jax.random.normal(
-        jax.random.PRNGKey(2), (2, m.input_hw, m.input_hw, m.input_ch)),
-        np.float32)[:1]
+    from repro.compiler import golden_frames
+    from repro.serving.server import compile_for_serving
+    prog = compile_for_serving(model)
+    frame = golden_frames(prog.model)[:1]
     y_eager = np.asarray(prog.run(frame))
     runner = prog.compile_runner(route="f32")
     acc0 = np.asarray(runner(runner.quantize(frame)))

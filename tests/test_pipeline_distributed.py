@@ -15,7 +15,6 @@ _SCRIPT = textwrap.dedent("""
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     import numpy as np
-    from repro.compat import set_mesh
     from repro.configs import ARCHS, reduced
     from repro.core import pipeline as PL
     from repro.models import transformer as TF
@@ -42,7 +41,7 @@ _SCRIPT = textwrap.dedent("""
         key = jax.random.PRNGKey(0)
         batch = {"tokens": jax.random.randint(key, (B, Sq), 0, 128),
                  "labels": jax.random.randint(key, (B, Sq), 0, 128)}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             loss = float(jax.jit(loss_fn)(params, batch))
             g = jax.jit(jax.grad(loss_fn))(params, batch)
             gn = float(sum(jnp.sum(jnp.abs(x.astype(jnp.float32)))
@@ -98,13 +97,13 @@ _SHARD_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
-    from repro.compat import set_mesh
     from repro.configs import ARCHS, reduced
     from repro.models import transformer as TF
     from repro.runtime import sharding as SH
     from repro.launch import steps as STEPS
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     for arch in ("yi-6b", "deepseek-v2-236b", "rwkv6-7b"):
         cfg = reduced(ARCHS[arch])
         params_sds, opt_sds = STEPS.abstract_state(cfg)
@@ -116,7 +115,7 @@ _SHARD_SCRIPT = textwrap.dedent("""
                  "labels": jnp.zeros((8, 16), jnp.int32)}
         bsh = SH.batch_shardings(mesh, jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             loss, _ = jax.jit(lambda p, b: TF.loss_fn(p, cfg, b))(
                 placed, jax.tree.map(jax.device_put, batch, bsh))
         assert bool(jnp.isfinite(loss)), arch

@@ -15,8 +15,9 @@ Scans README.md, DESIGN.md, and docs/OPERATIONS.md for
   the target must exist in the Makefile (prose like "make this fast"
   is not an invocation);
 * ``--flag`` tokens — the flag must be declared by some
-  ``add_argument`` under ``src/repro/launch/`` or ``benchmarks/``
-  (plus a small allowlist for flags owned by other tools: XLA, pytest).
+  ``add_argument`` under ``src/repro/launch/``, ``benchmarks/`` or in
+  ``chip_smoke.py`` (plus a small allowlist for flags owned by other
+  tools: XLA, pytest).
 
 Pure text scan — no jax import, no repo code import — so it runs in the
 lint job in seconds. Exit status 1 lists every dangling reference.
@@ -52,7 +53,7 @@ FLAG_ALLOW = {
 def _declared_flags() -> set[str]:
     flags: set[str] = set(FLAG_ALLOW)
     for pattern in ("src/repro/launch/*.py", "benchmarks/*.py",
-                    "tools/*.py"):
+                    "tools/*.py", "chip_smoke.py"):
         for py in ROOT.glob(pattern):
             flags.update(FLAG_RE.findall(py.read_text()))
     return flags
