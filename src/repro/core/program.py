@@ -43,6 +43,7 @@ steady-state pipeline.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Callable
 
 import jax
@@ -288,7 +289,12 @@ class EngineProgram:
         device. The kernel route runs the Pallas kernel in interpret mode
         only on a CPU device. Placement never changes the integers: every
         route is bit-exact on any backend, so placed output == unplaced
-        output (pinned by ``tests/test_serving.py``)."""
+        output (pinned by ``tests/test_serving.py``).
+
+        The jitted function is named ``serve_<model>_<start>_<stop>``
+        (the model name in identifier characters), so a profiler trace
+        names each stage's device program ``jit_serve_<model>_<start>_
+        <stop>``, the same in every process."""
         if self.steps is None:
             raise ValueError(
                 "plan-only program (compiled without params) cannot run")
@@ -322,6 +328,9 @@ class EngineProgram:
                     xq = _step_oracle(xq, step, bits)
             return xq
 
+        chain.__name__ = (
+            f"serve_{re.sub(r'[^0-9A-Za-z_]', '_', self.model.name)}"
+            f"_{start}_{stop}")
         fn = jax.jit(chain, donate_argnums=(0,) if donate else ())
         return CompiledRunner(program=self, route=route, donate=donate,
                               fn=fn, weights=weights, start=start,

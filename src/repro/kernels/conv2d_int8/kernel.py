@@ -104,6 +104,7 @@ def gemm_int8(x: jnp.ndarray, w: jnp.ndarray, shift: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((Np, Mp), out_dt),
         scratch_shapes=[pltpu.VMEM((bn_, bm_), jnp.int32)],
         interpret=interpret,
+        name="gemm_int8",
     )(xp, wp, bp, sp)
     return out[:N, :M]
 

@@ -76,6 +76,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.estimator import ServiceTimeEstimator, window_key
 
@@ -986,9 +987,19 @@ class AsyncFrontend:
     def _assemble(self, first: tuple) -> None:
         """Grow a single-tenant micro-batch from ``first`` until
         batch_size, the max_wait timeout, or — the expedited flush —
-        the tightest member deadline, then dispatch it. Fill pops are
-        pinned to the first request's tenant: models take different
-        frame shapes, so a batch can never mix tenants."""
+        the tightest member deadline, then dispatch it. The
+        ``serve.assemble`` span covers the batcher's time from the
+        first pop to the dispatch."""
+        with TraceAnnotation("serve.assemble"):
+            batch, reason = self._fill(first)
+        self._dispatch(batch, reason)
+
+    def _fill(self, first: tuple) -> tuple[list, str]:
+        """The assembled batch and why it closed (``full``,
+        ``timeout`` or ``deadline``). Fill pops are pinned to the first
+        request's tenant: models take different frame shapes, so a
+        batch can never mix tenants. Each blocking pop is a
+        ``serve.fill.wait`` span."""
         tenant = first[0].tenant
         batch = [first]
         first[0].t_batched = time.perf_counter()
@@ -1029,12 +1040,13 @@ class AsyncFrontend:
             if now >= flush_at:
                 reason = "timeout"
                 break
-            nxt = self._pop_next(
-                timeout=min(flush_at - now, urgent_at - now, 0.05),
-                tenant=tenant)
+            with TraceAnnotation("serve.fill.wait"):
+                nxt = self._pop_next(
+                    timeout=min(flush_at - now, urgent_at - now, 0.05),
+                    tenant=tenant)
             if nxt is not None:
                 take(nxt)
-        self._dispatch(batch, reason)
+        return batch, reason
 
     def _dispatch(self, batch, reason: str) -> None:
         """Hand one assembled micro-batch to the executor. Members whose
@@ -1101,8 +1113,9 @@ class AsyncFrontend:
             else:
                 self.stats.flushes_timeout += 1
         try:
-            frames = np.stack([f for _, f in live])
-            self.executor.submit_batch(frames, len(frames), tag=reqs)
+            with TraceAnnotation("serve.dispatch"):
+                frames = np.stack([f for _, f in live])
+                self.executor.submit_batch(frames, len(frames), tag=reqs)
         except BaseException as e:  # noqa: BLE001 - resolved per request
             for r in reqs:
                 r._fail(e)

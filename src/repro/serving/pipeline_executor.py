@@ -32,6 +32,7 @@ import time
 from typing import Callable, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.executor import (ServeStats, normalize_frames,
                                  pad_micro_batch)
@@ -202,11 +203,17 @@ class PipelineExecutor:
         """Dispatch one float micro-batch ``[B, H, W, C]`` (padded with
         zero frames to the compiled batch size if short). Quantizes on the
         calling thread — the host half of the stage-0 double buffer — and
-        blocks when the stage-0 queue is full (backpressure)."""
+        blocks when the stage-0 queue is full (backpressure).
+
+        The ``serve.quantize`` span's ``batch`` is the sequence number
+        this batch takes when it is the only producer, as a frontend's
+        batcher thread is; concurrent producers may swap those numbers
+        (the enqueue, under the order lock, is always exact)."""
         self._check_error()
         self.start()
-        frames = pad_micro_batch(self.program, frames, self.batch_size)
-        xq = self.runners[0].quantize(frames)
+        with TraceAnnotation("serve.quantize", batch=self._submitted):
+            frames = pad_micro_batch(self.program, frames, self.batch_size)
+            xq = self.runners[0].quantize(frames)
         # seq assignment and the stage-0 enqueue must be one atomic step,
         # or two producers could enter the FIFO out of submission order
         # (and a close() racing a blocked producer could slot its stop
@@ -224,7 +231,8 @@ class PipelineExecutor:
                 self.stats.batches += 1
                 self.stats.frames += n_valid
                 self.stats.padded_frames += len(frames) - n_valid
-            self._put(self._queues[0], ("batch", seq, tag, xq, n_valid))
+            with TraceAnnotation("serve.enqueue.wait", batch=seq):
+                self._put(self._queues[0], ("batch", seq, tag, xq, n_valid))
 
     def serve(self, frames: Iterable[np.ndarray]) -> list[np.ndarray]:
         """Convenience: submit a finite stream and drain."""
@@ -328,7 +336,8 @@ class PipelineExecutor:
         """Run stage i: pull a micro-batch, execute the stage's jitted
         range, hand the int8 boundary activations (or final accumulators)
         to the next queue. FIFO queues + one thread per stage preserve
-        submission order end to end."""
+        submission order end to end. The ``serve.stage`` span times the
+        same interval ``stage_busy_s`` adds."""
         runner = self.runners[i]
         q_in, q_out = self._queues[i], self._queues[i + 1]
         while True:
@@ -339,19 +348,24 @@ class PipelineExecutor:
             kind, seq, tag, payload, n_valid = item
             if kind == "batch":
                 try:
-                    t0 = time.perf_counter()
-                    out = runner(payload)
-                    out.block_until_ready()
-                    self.stage_busy_s[i] += time.perf_counter() - t0
+                    with TraceAnnotation("serve.stage", batch=seq, stage=i):
+                        t0 = time.perf_counter()
+                        out = runner(payload)
+                        with TraceAnnotation("serve.stage.ready.wait",
+                                             batch=seq, stage=i):
+                            out.block_until_ready()
+                        self.stage_busy_s[i] += time.perf_counter() - t0
                     item = ("batch", seq, tag, out, n_valid)
                 except BaseException as e:  # noqa: BLE001 - forwarded
                     self._fail(e)
                     item = ("err", seq, tag, e, n_valid)
-            q_out.put(item)
+            with TraceAnnotation("serve.handoff.wait", batch=seq, stage=i):
+                q_out.put(item)
 
     def _collector(self) -> None:
         """Final stage: dequantize/argmax on the host (overlapping the
-        device stages), deliver results, account completion."""
+        device stages), deliver results, account completion — one
+        ``serve.collect`` span a batch."""
         runner = self.runners[-1]
         q = self._queues[-1]
         while True:
@@ -359,39 +373,42 @@ class PipelineExecutor:
             if item[0] == "stop":
                 return
             kind, seq, tag, payload, n_valid = item
-            out = None
-            if kind == "batch":
-                try:
-                    out = runner.dequantize(payload)[:n_valid]
-                    if self.output == "top1":
-                        # reshape(0, -1) is ill-posed for an all-padding
-                        # batch; its top-1 is just empty.
-                        out = (np.argmax(out.reshape(n_valid, -1), axis=-1)
-                               if n_valid else
-                               np.zeros((0,), dtype=np.int64))
-                except BaseException as e:  # noqa: BLE001 - recorded
-                    self._fail(e)
-                    kind, payload = "err", e
-            with self._done:
-                if self._collected == 0 and self._first_t0 is not None:
-                    # First micro-batch traverses K cold jits serially —
-                    # pipeline fill + compile, charged apart from steady
-                    # state exactly like EngineExecutor's first batch.
-                    self.stats.first_batch_s = (time.perf_counter()
-                                                - self._first_t0)
-                self._collected += 1
+            with TraceAnnotation("serve.collect", batch=seq):
+                out = None
                 if kind == "batch":
-                    if tag is None:
-                        self._results.append(out)
-                self._done.notify_all()
-            if tag is not None:
-                try:
-                    if kind == "batch" and self.on_result:
-                        self.on_result(tag, out)
-                    elif kind == "err" and self.on_error:
-                        # A failed tagged batch must still answer its
-                        # requests — deliver the stage error instead of
-                        # leaving the futures hanging.
-                        self.on_error(tag, payload)
-                except BaseException as e:  # noqa: BLE001 - recorded
-                    self._fail(e)
+                    try:
+                        with TraceAnnotation("serve.dequantize", batch=seq):
+                            out = runner.dequantize(payload)[:n_valid]
+                        if self.output == "top1":
+                            # reshape(0, -1) is ill-posed for an all-padding
+                            # batch; its top-1 is just empty.
+                            out = (np.argmax(out.reshape(n_valid, -1), axis=-1)
+                                   if n_valid else
+                                   np.zeros((0,), dtype=np.int64))
+                    except BaseException as e:  # noqa: BLE001 - recorded
+                        self._fail(e)
+                        kind, payload = "err", e
+                with self._done:
+                    if self._collected == 0 and self._first_t0 is not None:
+                        # First micro-batch traverses K cold jits serially —
+                        # pipeline fill + compile, charged apart from steady
+                        # state exactly like EngineExecutor's first batch.
+                        self.stats.first_batch_s = (time.perf_counter()
+                                                    - self._first_t0)
+                    self._collected += 1
+                    if kind == "batch":
+                        if tag is None:
+                            self._results.append(out)
+                    self._done.notify_all()
+                if tag is not None:
+                    try:
+                        with TraceAnnotation("serve.deliver", batch=seq):
+                            if kind == "batch" and self.on_result:
+                                self.on_result(tag, out)
+                            elif kind == "err" and self.on_error:
+                                # A failed tagged batch must still answer
+                                # its requests — deliver the stage error
+                                # instead of leaving the futures hanging.
+                                self.on_error(tag, payload)
+                    except BaseException as e:  # noqa: BLE001 - recorded
+                        self._fail(e)

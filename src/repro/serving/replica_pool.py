@@ -44,6 +44,7 @@ import time
 from typing import Callable, Iterable, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.executor import ServeStats, normalize_frames
 from repro.core.program import EngineProgram
@@ -252,13 +253,16 @@ class ReplicaPool:
         dispatch it there. Blocks when that replica's stage-0 queue is
         full (per-replica backpressure). Thread-safe; results may
         complete out of submission order across replicas (drain reorders
-        by sequence number)."""
+        by sequence number). The pick is a ``serve.route`` span; the
+        replica's own spans follow."""
         self._check_error()
         n_valid = int(n_valid)
         with self._order_lock:
             if self._closed:
                 raise RuntimeError("ReplicaPool is closed")
-            r = self.router.pick()
+            with TraceAnnotation("serve.route", batch=self._submitted) as span:
+                r = self.router.pick()
+                span.set_metadata(replica=r)
             now = time.perf_counter()
             with self._lock:
                 if self._t0 is None:

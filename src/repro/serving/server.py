@@ -571,7 +571,11 @@ class Server:
     def stats(self) -> dict:
         """Per-tenant rollups across every frontend this server minted:
         calibration numbers per model plus outcome counters and
-        end-to-end latency percentiles, and fleet totals."""
+        end-to-end latency percentiles, and fleet totals.
+
+        ``stage_executables`` lists, per replica (one list unreplicated),
+        each stage's compiled executables: a value above 1 names the
+        stage that compiled a second shape."""
         models: dict[str, dict] = {}
         samples: dict[str, list] = {}
         for name, rt in self._runtimes.items():
@@ -583,6 +587,10 @@ class Server:
                             else round(rt.lat1_s * 1e3, 3)),
                 "replicas": getattr(rt.executor, "n_replicas", 1),
                 "stages": rt.executor.partition.n_stages,
+                "stage_executables": [
+                    [runner.cache_size() for runner in rep.runners]
+                    for rep in getattr(rt.executor, "replicas", None)
+                    or [rt.executor]],
                 **{k: 0 for k in _OUTCOME_KEYS},
                 "latency_ms_p50": None,
                 "latency_ms_p95": None,
