@@ -24,7 +24,7 @@ import collections
 import dataclasses
 import threading
 import time
-from typing import Callable, Iterable
+from typing import Callable, Iterable, Sequence
 
 import jax
 import numpy as np
@@ -59,26 +59,28 @@ def normalize_frames(program: EngineProgram,
     return frames
 
 
-def pad_micro_batch(program: EngineProgram, frames: np.ndarray,
-                    batch_size: int) -> np.ndarray:
-    """Validate a ``[B, H, W, C]`` micro-batch against ``program``'s input
-    spec and zero-pad it to ``batch_size`` (the fixed compiled shape) —
-    the one batch-shaping rule both the single-jit and the pipelined
-    executor share."""
-    frames = np.asarray(frames)
+def pad_micro_batch(program: EngineProgram,
+                    frames: Sequence[np.ndarray] | np.ndarray,
+                    batch_size: int) -> Sequence[np.ndarray] | np.ndarray:
+    """Validate a micro-batch (a sequence of ``[H, W, C]`` frames or a
+    ``[B, H, W, C]`` array) against ``program``'s input spec and pad it
+    to ``batch_size`` (the fixed compiled shape) with one shared zero
+    frame, without stacking it — the one batch-shaping rule both the
+    single-jit and the pipelined executor share. The quantize copies
+    each frame once, into the int8 batch's float32 scratch."""
     hw = program.model.input_hw
-    if frames.ndim != 4 or frames.shape[1:] != (hw, hw,
-                                                program.model.input_ch):
-        raise ValueError(
-            f"micro-batch shape {frames.shape} does not match the "
-            f"compiled program [B, {hw}, {hw}, {program.model.input_ch}]")
+    want = (hw, hw, program.model.input_ch)
+    for f in frames:
+        if np.shape(f) != want:
+            raise ValueError(
+                f"micro-batch frame shape {np.shape(f)} does not match "
+                f"the compiled program {want}")
     if len(frames) > batch_size:
         raise ValueError(f"micro-batch of {len(frames)} exceeds the "
                          f"compiled batch size {batch_size}")
     if len(frames) < batch_size:
-        pad = np.zeros((batch_size - len(frames),) + frames.shape[1:],
-                       frames.dtype)
-        frames = np.concatenate([frames, pad], axis=0)
+        zero = np.zeros(want, np.float32)
+        frames = [*frames, *[zero] * (batch_size - len(frames))]
     return frames
 
 
@@ -163,11 +165,12 @@ class EngineExecutor:
                     self._dispatch(self._pending[:self.batch_size])
                     self._pending = self._pending[self.batch_size:]
 
-    def submit_batch(self, frames: np.ndarray, n_valid: int,
-                     tag: object = None) -> None:
-        """Dispatch one pre-assembled micro-batch ``[B, H, W, C]``
-        directly (padded with zero frames to the compiled batch size if
-        short), bypassing the pending buffer — the entry point the async
+    def submit_batch(self, frames: Sequence[np.ndarray] | np.ndarray,
+                     n_valid: int, tag: object = None) -> None:
+        """Dispatch one pre-assembled micro-batch (a sequence of
+        ``[H, W, C]`` frames or a ``[B, H, W, C]`` array) directly
+        (padded with zero frames to the compiled batch size if short),
+        bypassing the pending buffer — the entry point the async
         frontend's batcher uses. ``tag`` is handed to ``on_result``
         with this batch's outputs. Thread-safe; blocks when
         ``max_inflight`` batches are already on device."""
@@ -209,8 +212,8 @@ class EngineExecutor:
     def _dispatch(self, frames, n_valid: int | None = None,
                   tag: object = None):
         """Host quantize-in + async device dispatch of one micro-batch
-        (a list of frames from the pending buffer, or an already-stacked
-        ``[B, H, W, C]`` array — no re-stacking copy on that path).
+        (a list of frames from the pending buffer, or a ``[B, H, W, C]``
+        array), quantized straight into the int8 batch.
         Blocks only when ``max_inflight`` batches are already on device
         (the double-buffer back-pressure). Caller holds the lock."""
         if self._t0 is None:
@@ -218,9 +221,7 @@ class EngineExecutor:
         while len(self._inflight) >= self._max_inflight:
             self._collect_one()
         n = n_valid if n_valid is not None else len(frames)
-        batch = (frames if isinstance(frames, np.ndarray)
-                 else np.stack(frames))
-        xq = self.runner.quantize(batch)
+        xq = self.runner.quantize(frames)
         t0 = time.perf_counter()
         acc = self.runner(xq)          # async: returns a device future
         if self.stats.batches == 0:

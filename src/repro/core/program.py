@@ -378,17 +378,20 @@ class CompiledRunner:
     def is_last(self) -> bool:
         return self.stop == len(self.program.steps)
 
-    def quantize(self, x: np.ndarray) -> np.ndarray:
-        """Host-side quantize onto the program's frozen input format
-        (numpy twin of ``quant.quantize_to_exponent`` — bit-identical).
-        Only the first stage consumes float frames."""
+    def quantize(self, frames) -> np.ndarray:
+        """Host-side quantize of float frames (a sequence of
+        ``[H, W, C]`` or an ``[N, H, W, C]`` array) onto the program's
+        frozen input format, into a fresh int8 batch:
+        ``quant.quantize_frames_np``, bit-identical to
+        ``quant.quantize_to_exponent``. Only the first stage consumes
+        float frames."""
         if not self.is_first:
             raise ValueError(
                 f"stage [{self.start}, {self.stop}) does not start the "
                 f"chain; it consumes the previous stage's quantized "
                 f"activations, not float frames")
-        return quant.quantize_to_exponent_np(
-            x, self.program.e_input, self.program.bits)
+        return quant.quantize_frames_np(frames, self.program.e_input,
+                                        self.program.bits)
 
     def __call__(self, xq) -> jnp.ndarray:
         """Dispatch one quantized batch; returns the device future of the

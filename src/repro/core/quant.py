@@ -46,16 +46,70 @@ def quantize_to_exponent(x: jnp.ndarray, e: int, bits: int = 8):
 
 
 def quantize_to_exponent_np(x, e: int, bits: int = 8):
-    """Numpy twin of :func:`quantize_to_exponent` for host-side
-    quantize-in (the serving executor overlaps it with device compute).
-    Bit-identical: same float32 multiply, same round-half-to-even, same
-    clip (``tests/test_executor.py::test_quantize_np_twin_bit_identical``
+    """Numpy twin of :func:`quantize_to_exponent`, whole array at once:
+    the plain reference the served quantize-in
+    (:func:`quantize_frames_np`) is held to. Bit-identical: same float32
+    multiply, same round-half-to-even, same clip
+    (``tests/test_executor.py::test_quantize_np_twin_bit_identical``
     pins the equivalence)."""
     import numpy as np
     qmax = 2 ** (bits - 1) - 1
     q = np.clip(np.rint(np.asarray(x, np.float32) * np.float32(2.0 ** (-e))),
                 -qmax - 1, qmax)
     return q.astype(np.int8 if bits <= 8 else np.int16)
+
+
+def quantize_frames_np(frames, e: int, bits: int = 8):
+    """Quantize float frames straight into a fresh ``[N, H, W, C]``
+    int8 batch (int16 for ``bits`` 16): the host quantize-in of a served
+    micro-batch.
+
+    ``frames`` is a sequence of ``[H, W, C]`` frames or an
+    ``[N, H, W, C]`` array. The result equals
+    :func:`quantize_to_exponent_np` of the stacked frames: each frame is
+    copied into one float32 scratch (converted as
+    ``np.asarray(x, np.float32)`` would), which is scaled, clamped to the
+    rails and rounded half to even in place, the rounding casting into
+    the result. Clamping before ``rint`` equals clipping after it, since
+    the rails are integers and ``rint`` is monotone
+    (``tests/test_executor.py`` pins the equivalence).
+
+    One copy a frame and three whole-batch passes, with no temporaries:
+    every numpy call releases and retakes the interpreter lock, and on a
+    busy server each retake waits behind the other threads' Python work,
+    so fewer, larger calls beat cache-sized ones. Both arrays keep the
+    frames' own memory order (batch outermost, as ``np.stack`` lays it
+    out), so strided frames are read in order. The scratch and the
+    result belong to this call alone: concurrent callers share nothing,
+    and stage 0 may alias or donate the result."""
+    import numpy as np
+    n = len(frames)
+    if isinstance(frames, np.ndarray):
+        shape, strides = frames.shape[1:], frames.strides[1:]
+    elif n:
+        first = np.asarray(frames[0])
+        shape, strides = first.shape, first.strides
+    else:
+        raise ValueError("no frames to quantize")
+    order = sorted(range(len(shape)), key=lambda a: -abs(strides[a]))
+    back = (0,) + tuple(1 + order.index(a) for a in range(len(shape)))
+
+    def alloc(rows, dtype, make):
+        return make((rows,) + tuple(shape[a] for a in order),
+                    dtype).transpose(back)
+
+    qmax = 2 ** (bits - 1) - 1
+    out = alloc(n, np.int8 if bits <= 8 else np.int16, np.empty)
+    scratch = alloc(n, np.float32, np.empty)
+    for i in range(n):
+        if np.shape(frames[i]) != shape:
+            raise ValueError(f"frame {i} has shape {np.shape(frames[i])}, "
+                             f"frame 0 {shape}")
+        scratch[i] = frames[i]
+    np.multiply(scratch, np.float32(2.0 ** (-e)), out=scratch)
+    np.clip(scratch, -qmax - 1, qmax, out=scratch)
+    np.rint(scratch, out=out, casting="unsafe")
+    return out
 
 
 def quantize_po2(x: jnp.ndarray, axis: int, bits: int = 8):

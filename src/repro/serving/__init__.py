@@ -25,7 +25,7 @@ single-jit :class:`~repro.core.executor.EngineExecutor`, and the
 per-tenant :class:`~repro.serving.server.TenantMux` all by construction.
 """
 
-from typing import Protocol, runtime_checkable
+from typing import Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
@@ -57,7 +57,9 @@ class Executor(Protocol):
                        failures (``None`` acceptable for executors that
                        raise synchronously from ``submit_batch``)
     ``submit_batch``   ``(frames, n_valid, tag=None)``: dispatch one
-                       micro-batch; blocks on internal backpressure
+                       micro-batch, a sequence of ``[H, W, C]`` frames
+                       (what the frontend passes) or an array; blocks on
+                       internal backpressure
     ``flush_inflight`` collect finished batches now (no-op for executors
                        whose collector thread runs continuously)
     ``reset_stats``    zero the executor's serve statistics (between
@@ -73,8 +75,8 @@ class Executor(Protocol):
     on_result: object
     on_error: object
 
-    def submit_batch(self, frames: np.ndarray, n_valid: int,
-                     tag: object = None) -> None: ...
+    def submit_batch(self, frames: Sequence[np.ndarray] | np.ndarray,
+                     n_valid: int, tag: object = None) -> None: ...
 
     def flush_inflight(self) -> None: ...
 

@@ -1114,8 +1114,8 @@ class AsyncFrontend:
                 self.stats.flushes_timeout += 1
         try:
             with TraceAnnotation("serve.dispatch"):
-                frames = np.stack([f for _, f in live])
-                self.executor.submit_batch(frames, len(frames), tag=reqs)
+                self.executor.submit_batch([f for _, f in live], len(live),
+                                           tag=reqs)
         except BaseException as e:  # noqa: BLE001 - resolved per request
             for r in reqs:
                 r._fail(e)

@@ -188,22 +188,24 @@ class PipelineExecutor:
         # a second producer could assemble and enqueue a later batch
         # between this one's assembly and its enqueue.
         with self._order_lock:
-            full: list[np.ndarray] = []
+            full: list[list[np.ndarray]] = []
             with self._lock:
                 for f in frames:
                     self._pending.append(f)
                     if len(self._pending) >= self.batch_size:
-                        full.append(np.stack(self._pending[:self.batch_size]))
+                        full.append(self._pending[:self.batch_size])
                         self._pending = self._pending[self.batch_size:]
             for batch in full:
                 self.submit_batch(batch, len(batch))
 
-    def submit_batch(self, frames: np.ndarray, n_valid: int,
-                     tag: object = None) -> None:
-        """Dispatch one float micro-batch ``[B, H, W, C]`` (padded with
-        zero frames to the compiled batch size if short). Quantizes on the
-        calling thread — the host half of the stage-0 double buffer — and
-        blocks when the stage-0 queue is full (backpressure).
+    def submit_batch(self, frames: Sequence[np.ndarray] | np.ndarray,
+                     n_valid: int, tag: object = None) -> None:
+        """Dispatch one float micro-batch (a sequence of ``[H, W, C]``
+        frames or a ``[B, H, W, C]`` array), padded with zero frames to
+        the compiled batch size if short. Quantizes on the calling
+        thread straight into a fresh int8 batch — the host half of the
+        stage-0 double buffer — and blocks when the stage-0 queue is
+        full (backpressure).
 
         The ``serve.quantize`` span's ``batch`` is the sequence number
         this batch takes when it is the only producer, as a frontend's
@@ -292,7 +294,7 @@ class PipelineExecutor:
             tail = self._pending
             self._pending = []
         if tail:
-            self.submit_batch(np.stack(tail), len(tail))
+            self.submit_batch(tail, len(tail))
         with self._done:
             while self._collected < self._submitted and self._error is None:
                 self._done.wait(timeout=0.1)

@@ -237,19 +237,20 @@ class ReplicaPool:
         else:
             frames = [np.asarray(frame)]
         with self._order_lock:
-            full: list[np.ndarray] = []
+            full: list[list[np.ndarray]] = []
             with self._lock:
                 for f in frames:
                     self._pending.append(f)
                     if len(self._pending) >= self.batch_size:
-                        full.append(np.stack(self._pending[:self.batch_size]))
+                        full.append(self._pending[:self.batch_size])
                         self._pending = self._pending[self.batch_size:]
             for batch in full:
                 self.submit_batch(batch, len(batch))
 
-    def submit_batch(self, frames: np.ndarray, n_valid: int,
-                     tag: object = None) -> None:
-        """Route one float micro-batch to the least-wait replica and
+    def submit_batch(self, frames: Sequence[np.ndarray] | np.ndarray,
+                     n_valid: int, tag: object = None) -> None:
+        """Route one float micro-batch (a sequence of frames or an
+        array, forwarded as it is) to the least-wait replica and
         dispatch it there. Blocks when that replica's stage-0 queue is
         full (per-replica backpressure). Thread-safe; results may
         complete out of submission order across replicas (drain reorders
@@ -295,7 +296,8 @@ class ReplicaPool:
                 raise
             self._maybe_probe(frames)
 
-    def _maybe_probe(self, frames: np.ndarray) -> None:
+    def _maybe_probe(self,
+                     frames: Sequence[np.ndarray] | np.ndarray) -> None:
         """Dispatch one all-padding probe batch when the router asks for
         one (an excluded replica is due its health check). Probes ride
         the live submit beat but live outside it: they never count in
@@ -391,7 +393,7 @@ class ReplicaPool:
             tail = self._pending
             self._pending = []
         if tail:
-            self.submit_batch(np.stack(tail), len(tail))
+            self.submit_batch(tail, len(tail))
         with self._done:
             while self._collected < self._submitted and self._error is None:
                 self._done.wait(timeout=0.1)

@@ -40,7 +40,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Callable, Iterator
+from typing import Callable, Iterator, Sequence
 
 import jax
 import numpy as np
@@ -318,8 +318,8 @@ class TenantMux:
         if cb is not None:
             cb(tag, exc)
 
-    def submit_batch(self, frames: np.ndarray, n_valid: int,
-                     tag=None) -> None:
+    def submit_batch(self, frames: Sequence[np.ndarray] | np.ndarray,
+                     n_valid: int, tag=None) -> None:
         """Dispatch one single-tenant micro-batch to its tenant's
         executor (blocking on that executor's own backpressure). The
         tag must be the frontend's request tuple — the tenant routing

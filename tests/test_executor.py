@@ -219,3 +219,98 @@ def test_quantize_np_twin_bit_identical():
             b = quant.quantize_to_exponent_np(x, e, bits)
             np.testing.assert_array_equal(a, b)
             assert b.dtype == (np.int8 if bits == 8 else np.int16)
+
+
+def _planted_frames(dtype, e, bits, n, layout):
+    """``n`` frames ``[7, 6, 5]`` of ``dtype``, float ones carrying the
+    ties and rails of the twin test plus ties and rails at the scale
+    ``2^e`` sets. ``layout``: ``contiguous``; ``strided`` (every other
+    row of a larger array); ``permuted`` (the frames interleaved in
+    memory and their channels outside their columns, as a frame pool
+    fetched from a device can be)."""
+    rng = np.random.default_rng(n)
+    if np.issubdtype(dtype, np.integer):
+        x = rng.integers(0, 256, (n, 7, 6, 5)).astype(dtype)
+    else:
+        x = rng.standard_normal((n, 7, 6, 5)) * 40
+        qmax = 2 ** (bits - 1) - 1
+        scaled = np.array([0.5, 2.5, -1.5, 127.5, -127.5, 128.5, -128.5,
+                           qmax + 0.5, -qmax - 0.5, -qmax - 1.5])
+        planted = np.concatenate([
+            [0.5, 1.5, 2.5, -0.5, -1.5, 300.0, -300.0, 0.0],
+            scaled * 2.0 ** e])
+        x.reshape(n, -1)[:, :len(planted)] = planted
+        x = x.astype(dtype)
+    if layout == "strided":
+        big = np.zeros((n, 14, 6, 5), dtype)
+        big[:, ::2] = x
+        return big[:, ::2]
+    if layout == "permuted":
+        return np.ascontiguousarray(x.transpose(1, 3, 0, 2)).transpose(
+            2, 0, 3, 1)
+    return x
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "strided", "permuted"])
+@pytest.mark.parametrize("form", ["list", "array"])
+@pytest.mark.parametrize("n", [1, 5, 8])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64, np.uint8])
+def test_quantize_frames_np_equals_padded_stack(dtype, n, form, layout):
+    """The quantize of frames straight into the int8 batch == stacking
+    the frames, zero-padding to the batch and quantizing the whole
+    batch: for every input dtype and memory layout, a sequence or an
+    array, short batches padded with a shared zero frame (as
+    ``pad_micro_batch`` pads them) and full ones, and every exponent and
+    width. The batch keeps the memory order ``np.stack`` gives the
+    frames."""
+    from repro.core import quant
+    for e in (-3, 0, 2):
+        for bits in (8, 16):
+            x = _planted_frames(dtype, e, bits, n, layout)
+            stacked = np.stack(list(x))
+            got = quant.quantize_frames_np(list(x) if form == "list" else x,
+                                           e, bits)
+            want = quant.quantize_to_exponent_np(stacked, e, bits)
+            assert got.dtype == want.dtype == (np.int8 if bits == 8
+                                               else np.int16)
+            np.testing.assert_array_equal(got, want)
+            assert (np.argsort(got.strides).tolist()
+                    == np.argsort(stacked.strides).tolist())
+            zero = np.zeros(x.shape[1:], np.float32)
+            padded = quant.quantize_frames_np(
+                [*x, *[zero] * (8 - n)], e, bits)
+            np.testing.assert_array_equal(
+                padded, quant.quantize_to_exponent_np(
+                    np.concatenate([stacked, np.zeros(
+                        (8 - n,) + x.shape[1:], stacked.dtype)]), e, bits))
+
+
+def test_quantize_frames_np_refuses_bad_frames():
+    """Frames of unequal shape and an empty sequence are refused, not
+    broadcast."""
+    from repro.core import quant
+    frames = [np.zeros((4, 4, 3), np.float32)] * 3
+    with pytest.raises(ValueError, match="shape"):
+        quant.quantize_frames_np(frames + [np.zeros((1, 4, 3))], 0, 8)
+    with pytest.raises(ValueError, match="no frames"):
+        quant.quantize_frames_np([], 0, 8)
+
+
+def test_runner_quantize_list_equals_array():
+    """``CompiledRunner.quantize`` of a frame list == of the stacked
+    array; a short batch padded by ``pad_micro_batch`` (one shared zero
+    frame, no stack) quantizes to the same rows and zero rows after."""
+    from repro.core.executor import pad_micro_batch
+    prog, frames = _tiny()
+    runner = prog.compile_runner(route="f32")
+    np.testing.assert_array_equal(runner.quantize(list(frames)),
+                                  runner.quantize(frames))
+    padded = pad_micro_batch(prog, frames[:3], 8)
+    assert len(padded) == 8 and not isinstance(padded, np.ndarray)
+    got = runner.quantize(padded)
+    np.testing.assert_array_equal(got[:3], runner.quantize(frames[:3]))
+    assert got.shape == (8,) + frames.shape[1:] and not got[3:].any()
+    with pytest.raises(ValueError, match="exceeds"):
+        pad_micro_batch(prog, frames, 8)
+    with pytest.raises(ValueError, match="does not match"):
+        pad_micro_batch(prog, [frames[0][:, :8]], 8)

@@ -291,6 +291,51 @@ def test_engine_executor_multi_producer_submit():
     assert ex.stats.frames == len(frames)
 
 
+def test_pipeline_concurrent_submit_batch_own_logits():
+    """Two threads call ``submit_batch`` at once with distinct frame
+    lists (full and short batches): each tag's logits are its own
+    frames' ``runner.logits``, bit for bit, so no quantize scratch or
+    int8 batch is shared between producers."""
+    import sys
+    prog, _ = _tiny()
+    runner = prog.compile_runner()
+    rng = np.random.default_rng(7)
+    work = {(t, k): list(rng.standard_normal((4 - k % 2, 16, 16, 4))
+                         .astype(np.float32) * (t + 1))
+            for t in range(2) for k in range(12)}
+    got: dict = {}
+    errors: list = []
+
+    def producer(t):
+        try:
+            for k in range(12):
+                px.submit_batch(work[t, k], len(work[t, k]), tag=(t, k))
+        except BaseException as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with PipelineExecutor(prog, stages=2, batch_size=4,
+                              output="logits",
+                              on_result=got.__setitem__) as px:
+            threads = [threading.Thread(target=producer, args=(t,))
+                       for t in range(2)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=120)
+                assert not th.is_alive()
+            assert px.wait_idle(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors
+    assert set(got) == set(work)
+    for tag, frames in work.items():
+        np.testing.assert_array_equal(np.stack(got[tag]),
+                                      runner.logits(frames))
+
+
 def test_frontend_over_engine_executor_multi_producer():
     """Many client threads -> AsyncFrontend -> thread-safe EngineExecutor:
     every request resolves to its own frame's exact logits."""
