@@ -1,7 +1,7 @@
 """Decide ``correct``: every answer the window was due to give, against
 the plain reference.
 
-The served path returns each frame's logits: the last layer's int32
+The served path returns each frame's logits: the output node's int32
 accumulators on their exact power-of-two scale. The reference computes
 the same integers, so the comparison is exact. Two numbers are compared,
 each with its limit:

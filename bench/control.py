@@ -3,12 +3,12 @@
     python3 bench/control.py --config alexnet --seeds 11 12 13
 
 For each seed it draws the run's inputs, builds the plain reference at
-the configuration's int8 and the control (the same reference with its
-weights held at int4, the next precision below), computes both over the
-seed's frame pool on the chip, and prints one JSON line: the control's
-``max_gap_lsb`` against the int8 reference, in the same units the
-benchmark's check uses, and how long the reference took. The control has
-to fail the check's limit on every seed.
+the configuration's int8 (a chain or a graph) and the control (the same
+reference with its weights held at int4, the next precision below),
+computes both over the seed's frame pool on the chip, and prints one
+JSON line: the control's ``max_gap_lsb`` against the int8 reference, in
+the same units the benchmark's check uses, and how long the reference
+took. The control has to fail the check's limit on every seed.
 """
 
 from __future__ import annotations

@@ -1,10 +1,11 @@
 """Everything a run feeds the system, made from ``--seed``.
 
 One jitted call on the device draws the float weights and biases of
-every compute layer, the calibration frame and the pool of frames that
-requests cycle through; the host gets them as numpy arrays, which is the
-form both the program's compiler and the reference take. Only the seed
-and the configuration's shapes decide the values.
+every conv and fc node of the network (``bench/graph.py``, either
+form), the calibration frame and the pool of frames that requests cycle
+through; the host gets them as numpy arrays, which is the form both the
+program's compiler and the reference take. Only the seed and the
+configuration's shapes decide the values.
 """
 
 from __future__ import annotations
@@ -15,21 +16,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import graph
+
 BIAS_STD = 0.05
 
 
 def _shapes(cfg: dict) -> dict:
-    out = {}
-    for lyr in cfg["layers"]:
-        if lyr["kind"] == "pool":
-            continue
-        if lyr["kind"] == "fc":
-            w = (lyr["in_ch"], lyr["out_ch"])
-        else:
-            k = lyr["kernel"]
-            w = (k, k, lyr["in_ch"] // lyr.get("groups", 1), lyr["out_ch"])
-        out[lyr["name"]] = w
-    return out
+    """Weight shape of every conv (HWIO) and fc ((in, out)) node, in the
+    network's order."""
+    return {n.name: n.weight_shape for n in graph.parse(cfg).compute()}
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
@@ -51,7 +46,7 @@ def make_inputs(cfg: dict, seed: int, device=None):
     """-> (params {layer: {"w", "b"}}, calibration batch [1, H, W, C],
     frame pool [P, H, W, C]), all float32 numpy."""
     shapes = tuple(_shapes(cfg).items())
-    frame = (cfg["input_hw"], cfg["input_hw"], cfg["input_ch"])
+    frame = graph.parse(cfg).frame
     with jax.default_device(device or jax.devices()[0]):
         key = jax.random.PRNGKey(seed)
         out = _draw(key, shapes, frame, int(cfg["pool_frames"]))

@@ -10,26 +10,30 @@ import jax
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import check, harness
+from bench.reference import cnn as reference
 from repro.core.program import CompiledRunner
 from repro.serving.frontend import AsyncFrontend
 
-TINY = json.loads((Path(__file__).resolve().parent / "tiny.json").read_text())
+HERE = Path(__file__).resolve().parent
+TINY = json.loads((HERE / "tiny.json").read_text())
+TINY_GRAPH = json.loads((HERE / "tiny_graph.json").read_text())
 CLOSED = {"loop": "closed", "clients": 8}
 OPEN = {"loop": "open", "scenario": "poisson", "rate_fps": 400}
 
 
-def _run(traffic=CLOSED, trace=False, cell="alexnet.closed"):
+def _run(traffic=CLOSED, trace=False, cell="alexnet.closed", cfg=TINY):
     bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
-    return harness.run_cell(bench, {"name": cell}, TINY, traffic, seed=2**31 + 3,
+    return harness.run_cell(bench, {"name": cell}, cfg, traffic, seed=2**31 + 3,
                             seconds=1.0, trace=trace, devices=jax.devices()[:1],
                             t_start=0.0, peaks=None)
 
 
-@pytest.mark.parametrize("traffic,cell", [(CLOSED, "alexnet.closed"),
-                                          (OPEN, "alexnet.closed")])
-def test_sound_run_is_correct(traffic, cell):
-    r = _run(traffic, cell=cell)
+@pytest.mark.parametrize("traffic,cell,cfg", [
+    (CLOSED, "alexnet.closed", TINY), (OPEN, "alexnet.closed", TINY),
+    (CLOSED, "alexnet.closed", TINY_GRAPH)], ids=["closed", "open", "graph"])
+def test_sound_run_is_correct(traffic, cell, cfg):
+    r = _run(traffic, cell=cell, cfg=cfg)
     assert r["correct"] is True
     assert r["attempted"] > 0 and r["failed"] == 0
     assert list(r) == ["correct", "attempted", "failed", "metrics", "device",
@@ -45,15 +49,35 @@ def _patch(monkeypatch, name, wrap):
                         lambda self, x: wrap(self, orig(self, x)))
 
 
-def test_answer_altered_where_produced(monkeypatch):
+@pytest.mark.parametrize("cfg", [TINY, TINY_GRAPH], ids=["chain", "graph"])
+def test_answer_altered_where_produced(monkeypatch, cfg):
     def one_lsb_up(runner, out):
         out = np.array(out)
         out.reshape(len(out), -1)[0, 0] += runner.program.out_scale()[0]
         return out
     _patch(monkeypatch, "dequantize", one_lsb_up)
-    r = _run()
+    r = _run(cfg=cfg)
     assert r["correct"] is False
     assert r["checks"]["max_gap_lsb"]["value"] == 1
+
+
+@pytest.mark.parametrize("cfg", [TINY, TINY_GRAPH], ids=["chain", "graph"])
+def test_int4_control_in_the_programs_place(monkeypatch, cfg):
+    """Every served answer replaced, before the check, by the control's:
+    the reference with its weights held at int4 on the same frame."""
+    orig = check.served_logits
+
+    def control_served(cfg, params, calib, pool, sent, **kw):
+        low = reference.build(cfg, params, calib, weight_bits=4)
+        got = reference.logits(low, pool)
+        for s in sent:
+            if s.answered:
+                s.value = got[s.frame]
+        return orig(cfg, params, calib, pool, sent, **kw)
+    monkeypatch.setattr(check, "served_logits", control_served)
+    r = _run(cfg=cfg)
+    assert r["correct"] is False
+    assert r["checks"]["max_gap_lsb"]["value"] > 3
 
 
 def test_answers_handed_to_the_wrong_requests(monkeypatch):

@@ -6,6 +6,7 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -39,34 +40,59 @@ def test_every_declared_cell_and_metric_has_its_files():
         assert callable(harness.reader(m["name"]))
 
 
-def test_added_files_are_found_without_a_code_edit(tmp_path):
-    """A configuration, a traffic mix, a cell and a per-layer metric
-    dropped into a copy run there as they are."""
+@pytest.mark.parametrize("config", ["tiny", "tiny_graph"])
+def test_added_files_are_found_without_a_code_edit(tmp_path, config):
+    """A configuration (a chain of layers, or a graph the program builds
+    through its compiler's front door), a traffic mix, a cell and a
+    per-layer metric dropped into a copy run there as they are, served
+    through ``Server`` and correct."""
     root = _copy(tmp_path)
-    (root / "bench" / "configs" / "tiny.json").write_text(
-        (HERE / "tiny.json").read_text())
+    (root / "bench" / "configs" / f"{config}.json").write_text(
+        (HERE / f"{config}.json").read_text())
     (root / "bench" / "traffic" / "closed-c4.json").write_text(
         json.dumps({"loop": "closed", "clients": 4}))
     (root / "bench" / "metrics" / "extra.sent.py").write_text(
         "def read(obs):\n    return len(obs.sent)\n")
     bench = json.loads((root / "BENCHMARK.json").read_text())
-    bench["workloads"].append({"name": "tiny.closed", "config": "tiny",
+    name = f"{config}.closed"
+    bench["workloads"].append({"name": name, "config": config,
                                "traffic": "closed-c4", "chips": 1,
                                "why": "test"})
     bench["per_layer"].append({
         "name": "extra.sent", "unit": "requests", "better": "higher",
         "source": "host_clock", "layer": "frontend", "moves": "frames_per_s",
-        "workloads": ["tiny.closed"]})
+        "workloads": [name]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
 
-    bench, cell, cfg, traffic = harness.load_cell("tiny.closed", root)
-    assert [m["name"] for m in harness.metrics_of(bench, "tiny.closed",
+    bench, cell, cfg, traffic = harness.load_cell(name, root)
+    assert [m["name"] for m in harness.metrics_of(bench, name,
                                                   "per_layer")] == ["extra.sent"]
     r = harness.run_cell(bench, cell, cfg, traffic, seed=11, seconds=0.5,
                          trace=True, devices=jax.devices()[:1], t_start=0.0,
                          peaks=None, root=root)
     assert r["correct"] is True
     assert r["metrics"]["extra.sent"]["value"] == r["attempted"] > 0
+
+
+def test_a_graph_the_program_refuses_fails_at_once_naming_the_node():
+    """tiny_residual.json stops at the compiler's front door within
+    seconds, before any input is drawn or server built, and the error
+    names a node of the graph: today's program refuses it at ``pool1``,
+    the first tensor with two consumers, before it reaches ``b1_add``.
+    Once the program runs residual graphs, this test becomes a run that
+    is ``correct``, as the graph case of the test above."""
+    from repro.compiler import UnsupportedOpError
+    cfg = json.loads((HERE / "tiny_residual.json").read_text())
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    t = time.perf_counter()
+    with pytest.raises(UnsupportedOpError) as err:
+        harness.run_cell(bench, {"name": "any"}, cfg,
+                         {"loop": "closed", "clients": 4}, seed=3,
+                         seconds=0.5, trace=False,
+                         devices=jax.devices()[:1], t_start=0.0, peaks=None)
+    assert time.perf_counter() - t < 10
+    assert err.value.node == "pool1"
+    assert "'pool1'" in str(err.value)
 
 
 def _run(cwd: Path, *args) -> subprocess.CompletedProcess:
