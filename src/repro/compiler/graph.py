@@ -1,8 +1,8 @@
 """Framework-neutral CNN graph IR — the importer's front door.
 
 The serving zoo executes :class:`~repro.core.workload.CNNModel` graphs
-(a linear chain of conv / fc / pool engine layers with ReLU fused into
-every non-final engine). Arbitrary CNNs arrive as *graphs* with explicit
+(conv / fc / pool / add engine layers in topological order, ReLU fused
+into the engine that feeds it). Arbitrary CNNs arrive as *graphs* with explicit
 activation and pooling nodes, so the importer needs a small neutral IR
 between "whatever the source framework says" and "what the engine can
 lower": typed nodes for ``conv`` / ``fc`` / ``relu`` / ``maxpool`` /
@@ -17,11 +17,11 @@ Two ingestion paths build this IR:
 * :mod:`repro.compiler.onnx_import` — an optional ONNX reader, guarded
   by ``importlib`` so the no-onnx environment stays fully functional.
 
-The IR deliberately represents *more* than the engine supports
-(``avgpool``, ``add``): rejection with a typed
-:class:`UnsupportedOpError` naming the offending node is the lowering
-pass's job (:mod:`repro.compiler.lower`), while malformed structure and
-shape mismatches are :class:`GraphError`\\ s raised here, at import.
+Rejecting what the engine cannot run with a typed
+:class:`UnsupportedOpError` naming the offending node is mostly the
+lowering pass's job (:mod:`repro.compiler.lower`); malformed structure
+and shape mismatches are :class:`GraphError`\\ s raised here, at
+import.
 
 Conventions: NHWC activations, square spatial dims (the engine's
 ``CNNModel`` carries one ``input_hw``), batch dimension implicit.
@@ -212,8 +212,9 @@ def _infer_shape(node: Node, shapes: dict[str, tuple[int, ...]]
     src = shapes[node.inputs[0]]
     if node.op == "conv":
         if len(src) != 3:
-            raise GraphError(f"node {node.name!r}: conv needs a spatial "
-                             f"(h, w, c) producer, got shape {src}")
+            raise UnsupportedOpError(
+                node.name, f"conv after flatten/fc — a conv needs a "
+                           f"spatial (h, w, c) producer, got shape {src}")
         hw, _, cin = src
         k = _square(node.name, "kernel", a["kernel"])
         stride = _square(node.name, "stride", a["stride"])

@@ -1,31 +1,30 @@
 """Lower an importer :class:`~repro.compiler.graph.Graph` onto the
 engine's contract.
 
-The engine executes a *linear chain* of :class:`~repro.core.workload
-.ConvLayer` records with a fixed fusion schedule: every non-final
-conv/fc engine applies bias + ReLU + requantize in its epilogue, the
-final engine emits raw accumulators, and max pooling runs as its own
-integer stage between engines (``core/program.py::_lower``). Lowering
-therefore has to *normalize* the explicit graph onto that shape:
+The engine executes a list of :class:`~repro.core.workload.ConvLayer`
+records in topological order, each naming its producers
+(``core/program.py``): conv/fc engines apply bias, their ReLU and a
+requantize to int8 in their epilogue, the output engine emits raw
+accumulators, and max pools, residual adds and average pools run as
+integer steps between engines. Lowering maps the explicit graph onto
+those layers:
 
-* **ReLU folding** — a ``relu`` node folds into the conv/fc that feeds
-  it. It may also sit *after* an intervening max pool (``conv -> pool
-  -> relu``): max and ReLU commute (both monotone), so the fold through
-  the pool is exact and the engine's ``conv(+relu) -> pool`` order
-  reproduces the source float semantics bit-for-bit.
-* **Contract checks** — every non-final compute layer must end up with
-  a ReLU (the engine fuses one unconditionally) and the final layer
-  must not (it emits accumulators); violations are typed
-  :class:`UnsupportedOpError`\\ s naming the layer rather than silently
-  computing something else.
-* **Legalization** — stride / padding / groups are re-derived through
-  the existing :class:`ConvLayer` fields: the layer's own
-  ``padding(in_hw)`` must reproduce the graph's declared (lo, hi) pads
-  exactly, otherwise the engine's window positions would shift.
-* **Rejection** — ops the IR carries but the engine cannot run
-  (``avgpool``: the integer pool stage is max-only; ``add``: no
-  residual datapath across the linear engine chain) raise
-  :class:`UnsupportedOpError`.
+* **ReLU folding** — a ``relu`` folds into the conv, fc or add that
+  feeds it when it is that producer's only consumer; every reader of
+  the ReLU (a block's next conv and its shortcut alike) then reads the
+  folded tensor. The ReLU may also sit *after* intervening max pools
+  (``conv -> pool -> relu``) whose only consumer it is: max and ReLU
+  commute (both monotone), so the fold through the pool is exact.
+  A conv or fc with no ReLU lowers with ``relu=False`` and still
+  requantizes (a bottleneck's last conv before its add).
+* **Legalization** — stride, groups and the graph's declared (lo, hi)
+  pads are carried onto the layer as they are, never re-derived.
+* **Rejection** — what the engine cannot run raises a typed
+  :class:`UnsupportedOpError` naming the node: a ReLU that cannot fold,
+  an output that is not a conv or fc, a trailing ReLU on the output,
+  and (at compile time, once exponents exist) an add whose operands lie
+  more than 23 bits apart. A conv after flatten is refused at import,
+  by shape inference.
 
 The output is a ready-to-compile ``(CNNModel, params-or-None)`` pair:
 params are assembled when the graph nodes carry weights (the ONNX
@@ -35,6 +34,9 @@ path), otherwise ``None`` and the caller seeds them
 
 from __future__ import annotations
 
+import dataclasses
+import math
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -42,14 +44,8 @@ from repro.compiler.graph import (Graph, Node, UnsupportedOpError,
                                   _square, resolve_padding)
 from repro.core.workload import CNNModel, ConvLayer
 
-_REJECT_WHY = {
-    "avgpool": "average pooling is not representable — the engine's "
-               "integer pool stage is max-only (exact on the po2 "
-               "format; an average needs a divider the fabric lacks)",
-    "add": "residual add is not representable — the engine executes a "
-           "linear chain of pipelined stages with no cross-stage "
-           "adder datapath",
-}
+_FOLDS_INTO = ("conv", "fc", "add")
+_FOLDS_THROUGH = ("maxpool", "flatten")   # both commute with ReLU exactly
 
 
 def lower_graph(graph: Graph) -> tuple[CNNModel, dict | None]:
@@ -57,146 +53,102 @@ def lower_graph(graph: Graph) -> tuple[CNNModel, dict | None]:
     :class:`CNNModel` (plus assembled params when the graph carries
     weights). Raises :class:`UnsupportedOpError` naming the first node
     the engine cannot take."""
-    _require_chain(graph)
-    layers: list[ConvLayer] = []
-    # Per compute layer: (node, relu_seen). The engine decides relu by
-    # position (all but last), so we collect then verify.
-    relu_of: dict[str, bool] = {}
-    compute_nodes: list[Node] = []
-    flattened = False
-    hw = graph.input_hw
+    consumers = graph.consumers()
+    by_name = {n.name: n for n in graph.nodes}
+    alias: dict[str, str] = {}      # relu/flatten -> the tensor it stands for
+    relu: set[str] = set()          # the conv/fc/add nodes a ReLU folds into
     for node in graph.nodes:
-        if node.op in _REJECT_WHY:
-            raise UnsupportedOpError(node.name, _REJECT_WHY[node.op])
-        if node.op == "conv":
-            if flattened:
-                raise UnsupportedOpError(
-                    node.name, "conv after flatten/fc — the engine "
-                               "chain cannot return to spatial layout")
-            layers.append(_lower_conv(node, hw, graph))
-            hw = graph.shapes[node.name][0]
-            compute_nodes.append(node)
-            relu_of[node.name] = False
-        elif node.op == "maxpool":
-            layers.append(_lower_pool(node, hw, graph))
-            hw = graph.shapes[node.name][0]
-        elif node.op == "fc":
-            flattened = True
-            fin = graph.shapes[node.inputs[0]][0]
-            layers.append(ConvLayer(node.name, fin,
-                                    int(node.attr("out_features")), 1,
-                                    kind="fc"))
-            compute_nodes.append(node)
-            relu_of[node.name] = False
+        if node.op == "flatten":      # the engine's fc reads NHWC flattened
+            alias[node.name] = alias.get(node.inputs[0], node.inputs[0])
         elif node.op == "relu":
-            producer = _relu_producer(node, graph)
-            if producer is None or producer.name not in relu_of:
+            producer = _relu_producer(node, by_name, consumers)
+            if producer is None:
                 raise UnsupportedOpError(
-                    node.name, "ReLU must follow a conv/fc engine "
-                               "(optionally through max pools, where "
-                               "the fold commutes exactly)")
-            if relu_of[producer.name]:
-                raise UnsupportedOpError(
-                    node.name, f"second ReLU folding into "
-                               f"{producer.name!r} — the engine epilogue "
-                               f"applies one")
-            relu_of[producer.name] = True
-        elif node.op == "flatten":
-            if len(graph.shapes[node.inputs[0]]) == 1:
-                continue                      # flat already: a no-op
-            flattened = True                  # engine folds it into fc
-        else:  # pragma: no cover - Graph.build already rejected it
-            raise UnsupportedOpError(node.name, f"op {node.op!r}")
-    if not compute_nodes:
+                    node.name, "ReLU must be the only consumer of the "
+                               "conv, fc or add it follows (optionally "
+                               "through max pools, where the fold "
+                               "commutes exactly)")
+            relu.add(producer.name)
+            alias[node.name] = alias.get(node.inputs[0], node.inputs[0])
+    layers = []
+    for node in graph.nodes:
+        if node.name in alias:
+            continue
+        inputs = tuple(alias.get(s, s) for s in node.inputs)
+        in_shape = graph.shapes[node.inputs[0]]
+        if node.op == "fc":
+            lyr = ConvLayer(node.name, math.prod(in_shape),
+                            int(node.attr("out_features")), 1, kind="fc")
+        elif node.op == "add":
+            lyr = ConvLayer(node.name, in_shape[-1], in_shape[-1], 1,
+                            kind="add", out_size=in_shape[0])
+        else:
+            lyr = _lower_window(node, in_shape)
+        layers.append(dataclasses.replace(lyr, inputs=inputs,
+                                          relu=node.name in relu))
+    out = _output(graph, by_name)
+    if out.name in relu:
         raise UnsupportedOpError(
-            graph.output, "graph has no conv/fc compute layer — nothing "
-                          "for the engine to run")
-    # The engine's fusion schedule: ReLU on every engine but the last.
-    for node in compute_nodes[:-1]:
-        if not relu_of[node.name]:
-            raise UnsupportedOpError(
-                node.name, "no ReLU activation — the engine fuses "
-                           "bias+ReLU+requantize into every non-final "
-                           "engine's epilogue and cannot skip the ReLU")
-    last = compute_nodes[-1]
-    if relu_of[last.name]:
-        raise UnsupportedOpError(
-            last.name, "trailing ReLU on the final layer — the final "
-                       "engine emits raw accumulators (logits); fold "
-                       "the activation into the consumer instead")
+            out.name, "trailing ReLU on the output layer — the output "
+                      "engine emits raw accumulators (logits); fold the "
+                      "activation into the consumer instead")
+    compute = [n for n in graph.nodes if n.op in ("conv", "fc")]
     model = CNNModel(graph.name, graph.input_hw, graph.input_ch,
                      tuple(layers))
-    return model, _collect_params(graph, compute_nodes)
+    return model, _collect_params(compute)
 
 
-def _require_chain(graph: Graph) -> None:
-    """The engine pipeline is linear: every node feeds exactly one
-    consumer (the terminal feeds none). Branching means a residual/
-    multi-head topology the chain cannot hold."""
-    consumers = graph.consumers()
-    for node in graph.nodes:
-        n = len(consumers.get(node.name, ()))
-        if n > 1:
-            names = [c.name for c in consumers[node.name]]
-            raise UnsupportedOpError(
-                node.name, f"feeds {n} consumers ({', '.join(names)}) — "
-                           f"the engine chain is linear (no fan-out)")
-
-
-def _lower_conv(node: Node, in_hw: int, graph: Graph) -> ConvLayer:
-    k = _square(node.name, "kernel", node.attr("kernel"))
-    stride = _square(node.name, "stride", node.attr("stride"))
-    lo, hi, out = resolve_padding(in_hw, k, stride, node.attr("padding"),
-                                  node.name)
-    cin = graph.shapes[node.inputs[0]][2]
-    layer = ConvLayer(node.name, cin, int(node.attr("out_channels")), k,
-                      stride=stride, groups=int(node.attr("groups")),
-                      out_size=out)
-    got = layer.padding(in_hw)
-    if got != (lo, hi):
+def _output(graph: Graph, by_name: dict[str, Node]) -> Node:
+    """The conv or fc whose accumulators are the network's output: the
+    graph's terminal, or the producer it reaches through max pools and
+    flattens."""
+    cur = by_name[graph.output]
+    if cur.op == "relu":
+        cur = by_name.get(cur.inputs[0], cur)
+    while cur.op in _FOLDS_THROUGH:
+        cur = by_name.get(cur.inputs[0], cur)
+    if cur.op not in ("conv", "fc"):
         raise UnsupportedOpError(
-            node.name, f"declared padding {node.attr('padding')!r} pads "
-                       f"(lo, hi)=({lo}, {hi}) but the engine derives "
-                       f"{got} for out={out} stride={stride} kernel={k} "
-                       f"on input {in_hw} — the window positions would "
-                       f"shift; use 'same', 'valid', or a symmetric pad "
-                       f"the output arithmetic reproduces")
-    return layer
+            graph.output, f"the output must be a conv or fc (through max "
+                          f"pools), whose int32 accumulators are the "
+                          f"logits; it is {cur.op} {cur.name!r}")
+    return cur
 
 
-def _lower_pool(node: Node, in_hw: int, graph: Graph) -> ConvLayer:
+def _lower_window(node: Node, in_shape: tuple[int, ...]) -> ConvLayer:
+    """A conv, max pool or average pool with its declared pads."""
     k = _square(node.name, "kernel", node.attr("kernel"))
-    stride = _square(node.name, "stride",
-                     node.attr("stride") if node.attr("stride") is not None
-                     else k)
-    lo, hi, out = resolve_padding(in_hw, k, stride, node.attr("padding"),
-                                  node.name)
-    ch = graph.shapes[node.name][2]
-    layer = ConvLayer(node.name, ch, ch, k, stride=stride, kind="pool",
-                      out_size=out)
-    got = layer.padding(in_hw)
-    if got != (lo, hi):
-        raise UnsupportedOpError(
-            node.name, f"declared padding {node.attr('padding')!r} pads "
-                       f"(lo, hi)=({lo}, {hi}) but the engine derives "
-                       f"{got} — max-pool windows would shift")
-    return layer
+    stride = node.attr("stride")
+    stride = _square(node.name, "stride", k if stride is None else stride)
+    lo, hi, out = resolve_padding(in_shape[0], k, stride,
+                                  node.attr("padding"), node.name)
+    if node.op == "conv":
+        return ConvLayer(node.name, in_shape[-1],
+                         int(node.attr("out_channels")), k, stride=stride,
+                         groups=int(node.attr("groups")), out_size=out,
+                         pad=(lo, hi))
+    return ConvLayer(node.name, in_shape[-1], in_shape[-1], k,
+                     stride=stride,
+                     kind="pool" if node.op == "maxpool" else "avgpool",
+                     out_size=out, pad=(lo, hi))
 
 
-def _relu_producer(node: Node, graph: Graph) -> Node | None:
-    """Walk back through max pools (and no-op flattens) to the conv/fc
-    a ReLU folds into. Max pool commutes with ReLU exactly, so the fold
-    is semantics-preserving; anything else in between breaks it."""
-    by_name = {n.name: n for n in graph.nodes}
+def _relu_producer(node: Node, by_name: dict[str, Node],
+                   consumers: dict[str, list[Node]]) -> Node | None:
+    """Walk back from a ReLU through max pools (and flattens) to the
+    conv, fc or add it folds into, each node on the way having one
+    consumer; None where anything else is in between."""
     cur = by_name.get(node.inputs[0])
-    while cur is not None and cur.op in ("maxpool", "flatten"):
+    while cur is not None and len(consumers[cur.name]) == 1:
+        if cur.op in _FOLDS_INTO:
+            return cur
+        if cur.op not in _FOLDS_THROUGH:
+            return None
         cur = by_name.get(cur.inputs[0])
-    if cur is not None and cur.op in ("conv", "fc"):
-        return cur
     return None
 
 
-def _collect_params(graph: Graph, compute_nodes: list[Node]) -> dict | None:
+def _collect_params(compute_nodes: list[Node]) -> dict | None:
     """Assemble a ``cnn.init_params``-shaped dict from node-attached
     weights. All-or-nothing: a graph with weights on only some compute
     layers is a broken export, not a half-seeded model."""

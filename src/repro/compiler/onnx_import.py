@@ -21,10 +21,10 @@ Gemm           ``fc`` (``transB`` honoured; ``alpha``/``beta`` must be 1;
                rows permuted from NCHW- to NHWC-flatten order)
 Relu           ``relu``
 MaxPool        ``maxpool``
-AveragePool /  ``avgpool`` (carried in the IR; the lowering pass rejects
-GlobalAverage  it with a typed :class:`UnsupportedOpError`)
+AveragePool /  ``avgpool`` (the engine's integer average pool; a global
+GlobalAverage  pool's window is its whole input)
 Flatten        ``flatten``
-Add            ``add`` (carried; rejected at lowering)
+Add            ``add`` (the engine's integer residual add)
 =============  ==========================================================
 
 Anything else raises :class:`UnsupportedOpError` naming the node —
@@ -134,6 +134,12 @@ def load_onnx(path: str | os.PathLike) -> Graph:
             k = _ints(attrs, "kernel_shape", name)
             s = _ints(attrs, "strides", name, default=list(k))
             p = _sym_pads(attrs, name)
+            if op == "avgpool" and p and (
+                    "count_include_pad" not in attrs
+                    or attrs["count_include_pad"].i != 1):
+                raise UnsupportedOpError(
+                    name, "padded AveragePool without count_include_pad=1 "
+                          "— the engine's average counts a pad as zeros")
             node = Node(op, name, srcs,
                         {"kernel": list(k), "stride": list(s),
                          "padding": p if p else "valid"})

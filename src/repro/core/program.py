@@ -53,7 +53,7 @@ import numpy as np
 from repro.core import quant
 from repro.core.allocator import (LayerAlloc, allocate_buffers,
                                   allocate_compute)
-from repro.core.workload import CNNModel, ConvLayer
+from repro.core.workload import COMPUTE, INPUT, CNNModel, ConvLayer
 
 Params = dict[str, Any]
 
@@ -79,44 +79,44 @@ def host_device():
 
 def float_forward(params: Params, model: CNNModel, x: jnp.ndarray,
                   record: dict[str, float] | None = None) -> jnp.ndarray:
-    """Reference float forward over the model graph (NHWC). With ``record``
-    it doubles as the calibration pass: per-layer output amax (post-ReLU
-    for hidden layers — what the next engine actually consumes) is stored
-    under the layer name, the network input under ``"__input__"``."""
+    """Reference float forward over the model graph (NHWC): the last
+    layer's output. With ``record`` it doubles as the calibration pass:
+    the output amax of each conv, fc and add (after its ReLU, where one
+    folds into it: what the next layers actually consume) is stored under
+    the layer name, the network input under ``"__input__"``."""
     if record is not None:
         record["__input__"] = float(jnp.max(jnp.abs(x)))
-    hw = x.shape[1]
-    last = [l for l in model.layers if l.kind != "pool"][-1]
-    for lyr in model.layers:
-        out_hw = lyr.out_hw(hw)
-        if lyr.kind == "pool":
+    env = {INPUT: x}
+    for lyr, src, hw in zip(model.layers, model.sources(), model.in_hws()):
+        x = env[src[0]]
+        if lyr.kind in ("pool", "avgpool"):
             lo, hi = lyr.padding(hw)
-            x = -jax.lax.reduce_window(
-                -x, jnp.inf, jax.lax.min,
-                (1, lyr.kernel, lyr.kernel, 1),
-                (1, lyr.stride, lyr.stride, 1),
-                ((0, 0), (lo, hi), (lo, hi), (0, 0)))
-        elif lyr.kind == "fc":
-            x = x.reshape(x.shape[0], -1)
-            w, b = params[lyr.name]["w"], params[lyr.name]["b"]
-            x = x @ w + b
-            if lyr is not last:
-                x = jax.nn.relu(x)
-            if record is not None:
-                record[lyr.name] = float(jnp.max(jnp.abs(x)))
+            win = ((1, lyr.kernel, lyr.kernel, 1),
+                   (1, lyr.stride, lyr.stride, 1),
+                   ((0, 0), (lo, hi), (lo, hi), (0, 0)))
+            if lyr.kind == "pool":
+                x = -jax.lax.reduce_window(-x, jnp.inf, jax.lax.min, *win)
+            else:
+                x = jax.lax.reduce_window(x, np.float32(0), jax.lax.add,
+                                          *win) / np.float32(lyr.kernel ** 2)
         else:
-            w, b = params[lyr.name]["w"], params[lyr.name]["b"]
-            lo, hi = lyr.padding(hw)
-            x = jax.lax.conv_general_dilated(
-                x, w, (lyr.stride, lyr.stride), ((lo, hi), (lo, hi)),
-                dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                feature_group_count=lyr.groups)
-            x = x + b
-            if lyr is not last:
+            if lyr.kind == "add":
+                x = x + env[src[1]]
+            elif lyr.kind == "fc":
+                w, b = params[lyr.name]["w"], params[lyr.name]["b"]
+                x = x.reshape(x.shape[0], -1) @ w + b
+            else:
+                w, b = params[lyr.name]["w"], params[lyr.name]["b"]
+                lo, hi = lyr.padding(hw)
+                x = jax.lax.conv_general_dilated(
+                    x, w, (lyr.stride, lyr.stride), ((lo, hi), (lo, hi)),
+                    dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                    feature_group_count=lyr.groups) + b
+            if model.has_relu(lyr):
                 x = jax.nn.relu(x)
             if record is not None:
                 record[lyr.name] = float(jnp.max(jnp.abs(x)))
-        hw = out_hw
+        env[lyr.name] = x
     return x
 
 
@@ -131,19 +131,22 @@ class EngineStep:
     shift schedule, and the spatial plumbing the kernel needs."""
 
     name: str
-    kind: str                      # "conv" | "fc" | "pool"
+    kind: str                      # "conv" | "fc" | "pool" | "avgpool" | "add"
     layer: ConvLayer
     pad: tuple[int, int]           # (lo, hi), both spatial dims
-    # compute-step payload (None for pool), host numpy arrays; a runner
-    # puts them on its device once:
+    inputs: tuple[str, ...] = (INPUT,)     # the tensors it reads, by name
+    # compute-step payload (None for the others), host numpy arrays; a
+    # runner puts them on its device once:
     wq: np.ndarray | None = None           # int8/int16 quantized weights
     bias_q: np.ndarray | None = None       # int32 bias on the acc format
-    shift: np.ndarray | None = None        # int32 [M]: e_out - (e_in+e_w)
+    # int32 [M]: e_out - (e_in+e_w); an add's one shift, e_out - e_in
+    shift: np.ndarray | None = None
     e_in: int = 0                          # input activation exponent
     e_w: np.ndarray | None = None          # int [M] weight exponents
     e_out: int = 0                         # output activation exponent
     relu: bool = False
     requantize: bool = True        # False on the last engine (emit acc32)
+    align: tuple[int, int] = (0, 0)        # add: left shifts to e_in
 
 
 @dataclasses.dataclass
@@ -177,10 +180,20 @@ class EngineProgram:
     # -- execution ----------------------------------------------------------
 
     def out_scale(self) -> np.ndarray:
-        """Per-channel float32 po2 scale of the final engine's int32
+        """Per-channel float32 po2 scale of the output layer's int32
         accumulators (logits = acc * out_scale, exactly)."""
-        last = [s for s in self.steps if s.kind != "pool"][-1]
-        return np.exp2(np.asarray(last.e_in + last.e_w, np.float32))
+        out = next(s for s in self.steps if s.name == self.model.output.name)
+        return np.exp2(np.asarray(out.e_in + out.e_w, np.float32))
+
+    def skip_across(self, cut: int) -> str | None:
+        """The first tensor a stage boundary before step ``cut`` would
+        split: made before ``cut`` (the network input counts), read at or
+        after it, and not step ``cut - 1``'s output, the one tensor a
+        boundary carries. None where the cut is legal."""
+        src = self.steps[cut - 1].name if cut else INPUT
+        made = {INPUT} | {s.name for s in self.steps[:cut]}
+        return next((n for s in self.steps[cut:] for n in s.inputs
+                     if n in made and n != src), None)
 
     def run(self, x: jnp.ndarray, *, use_kernel: bool = False) -> jnp.ndarray:
         """Fixed-point forward, eagerly step by step. ``x`` is float NHWC;
@@ -195,13 +208,13 @@ class EngineProgram:
             require_kernel(self.bits)
             interpret = _kernel_interpret(jax.devices()[0])
         xq = quant.quantize_to_exponent(x, self.e_input, self.bits)
-        for step in self.steps:
-            if step.kind == "pool":
-                xq = _pool_int(xq, step)
-            elif use_kernel:
-                xq = _step_kernel(xq, step, interpret)
-            else:
-                xq = _step_oracle(xq, step, self.bits)
+        if use_kernel:
+            def mac(xq, step):
+                return _step_kernel(xq, step, interpret)
+        else:
+            def mac(xq, step):
+                return _step_oracle(xq, step, self.bits)
+        xq = _walk(self.steps, xq, INPUT, mac, self.bits)
         scale = jnp.asarray(self.out_scale())
         return xq.astype(jnp.float32) \
             * scale.reshape((1,) * (xq.ndim - 1) + (-1,))
@@ -303,6 +316,13 @@ class EngineProgram:
                 f"stage range [{start}, {stop}) outside the "
                 f"{len(self.steps)}-step chain")
         steps = tuple(self.steps[start:stop])
+        skip = self.skip_across(start)
+        if skip is not None:
+            raise ValueError(
+                f"stage [{start}, {stop}) would start across skip tensor "
+                f"{skip!r}: one tensor, the previous step's output, "
+                f"crosses a stage boundary")
+        src = self.steps[start - 1].name if start else INPUT
         route = self._resolve_route(route, steps)
         target = device if device is not None else jax.devices()[0]
         interpret = (_kernel_interpret(target) if route == "kernel"
@@ -312,21 +332,17 @@ class EngineProgram:
         bits = self.bits
         weights = jax.device_put(
             {s.name: {"wq": s.wq, "bias_q": s.bias_q, "shift": s.shift}
-             for s in steps if s.kind != "pool"}, device)
+             for s in steps if s.kind in COMPUTE}, device)
 
         def chain(xq: jnp.ndarray, weights: dict) -> jnp.ndarray:
-            for step in steps:
-                if step.kind == "pool":
-                    xq = _pool_int(xq, step)
-                    continue
+            def mac(xq, step):
                 step = dataclasses.replace(step, **weights[step.name])
                 if route == "kernel":
-                    xq = _step_kernel(xq, step, interpret)
-                elif route == "f32":
-                    xq = _step_exact_f32(xq, step)
-                else:
-                    xq = _step_oracle(xq, step, bits)
-            return xq
+                    return _step_kernel(xq, step, interpret)
+                if route == "f32":
+                    return _step_exact_f32(xq, step)
+                return _step_oracle(xq, step, bits)
+            return _walk(steps, xq, src, mac, bits)
 
         chain.__name__ = (
             f"serve_{re.sub(r'[^0-9A-Za-z_]', '_', self.model.name)}"
@@ -478,6 +494,58 @@ def require_kernel(bits: int = 8) -> None:
 # ---------------------------------------------------------------------------
 # Step executors
 # ---------------------------------------------------------------------------
+
+
+def _walk(steps, xq, src: str, mac, bits: int):
+    """Run ``steps`` in order over a map of named tensors that starts as
+    ``{src: xq}``, dropping each tensor after its last reader; returns the
+    last step's output. ``mac(x, step)`` runs a conv or fc step; pools,
+    adds and average pools are plain integer ops here on every route."""
+    last = {n: i for i, s in enumerate(steps) for n in s.inputs}
+    env = {src: xq}
+    for i, step in enumerate(steps):
+        args = [env[n] for n in step.inputs]
+        for n in step.inputs:
+            if last[n] == i:
+                env.pop(n, None)
+        if step.kind == "pool":
+            env[step.name] = _pool_int(args[0], step)
+        elif step.kind == "add":
+            with jax.named_scope(step.name):
+                env[step.name] = _add_int(*args, step, bits)
+        elif step.kind == "avgpool":
+            with jax.named_scope(step.name):
+                env[step.name] = _avgpool_int(args[0], step)
+        else:
+            env[step.name] = mac(args[0], step)
+    return env[steps[-1].name]
+
+
+def _add_int(a: jnp.ndarray, b: jnp.ndarray, step: EngineStep,
+             bits: int) -> jnp.ndarray:
+    """Residual add: both operands shifted left in int32 onto the finer
+    of their exponents (``step.align``), summed exactly, ReLU where one
+    folds, then requantized to the add's own exponent with the conv
+    epilogue's shift (floor right, saturating left) and clipped."""
+    s = (jnp.left_shift(a.astype(jnp.int32), step.align[0])
+         + jnp.left_shift(b.astype(jnp.int32), step.align[1]))
+    if step.relu:
+        s = jnp.maximum(s, 0)
+    return quant.requantize_output(s, 0, step.shift, bits=bits)
+
+
+def _avgpool_int(xq: jnp.ndarray, step: EngineStep) -> jnp.ndarray:
+    """Average pool on the integers: the window's int32 sum (a pad counts
+    as zeros) over its size ``n``, rounded half up as ``(s + n // 2) //
+    n`` in floor division; the exponent passes through."""
+    lyr = step.layer
+    lo, hi = step.pad
+    n = lyr.kernel * lyr.kernel
+    s = jax.lax.reduce_window(
+        xq.astype(jnp.int32), jnp.int32(0), jax.lax.add,
+        (1, lyr.kernel, lyr.kernel, 1), (1, lyr.stride, lyr.stride, 1),
+        ((0, 0), (lo, hi), (lo, hi), (0, 0)))
+    return ((s + n // 2) // n).astype(xq.dtype)
 
 
 def _pool_int(xq: jnp.ndarray, step: EngineStep) -> jnp.ndarray:
@@ -667,23 +735,42 @@ def compile_model(model: CNNModel, params: Params | None = None, *,
 
 def _lower(model: CNNModel, params: Params, amax: dict[str, float],
            e_input: int, bits: int) -> list[EngineStep]:
+    """One step per layer, each tensor on its frozen exponent: a conv or
+    fc reads its input's and writes its own calibrated one, an add writes
+    its own, pools keep their input's."""
     steps: list[EngineStep] = []
-    compute = [l for l in model.layers if l.kind != "pool"]
-    last = compute[-1]
-    hw = model.input_hw
-    e_act = e_input
-    for lyr in model.layers:
+    e = {INPUT: e_input}
+    out = model.output.name
+    for lyr, src, hw in zip(model.layers, model.sources(), model.in_hws()):
+        e_act = e[src[0]]
         pad = lyr.padding(hw)
-        if lyr.kind == "pool":
-            steps.append(EngineStep(name=lyr.name, kind="pool", layer=lyr,
-                                    pad=pad))
-            hw = lyr.out_hw(hw)
+        if lyr.kind in ("pool", "avgpool"):
+            steps.append(EngineStep(name=lyr.name, kind=lyr.kind, layer=lyr,
+                                    pad=pad, inputs=src, e_in=e_act,
+                                    e_out=e_act))
+            e[lyr.name] = e_act
+            continue
+        e_out = quant.po2_exponent(amax[lyr.name], bits)
+        e[lyr.name] = e_out
+        if lyr.kind == "add":
+            fine = min(e_act, e[src[1]])
+            align = (e_act - fine, e[src[1]] - fine)
+            if max(align) > 31 - bits:
+                from repro.compiler.graph import UnsupportedOpError
+                raise UnsupportedOpError(
+                    lyr.name, f"operand exponents {e_act} and {e[src[1]]} "
+                              f"lie more than {31 - bits} bits apart: "
+                              f"aligned int{bits} operands would overflow "
+                              f"the int32 sum")
+            steps.append(EngineStep(
+                name=lyr.name, kind="add", layer=lyr, pad=pad, inputs=src,
+                shift=np.int32(np.clip(e_out - fine, -31, 31)), e_in=fine,
+                e_out=e_out, relu=model.has_relu(lyr), align=align))
             continue
         w = params[lyr.name]["w"]
         b = params[lyr.name]["b"]
         e_w = np.asarray(quant.po2_scale(w, axis=-1, bits=bits), np.int64)
-        is_last = lyr is last
-        e_out = quant.po2_exponent(amax[lyr.name], bits)
+        is_last = lyr.name == out
         # Floor each channel's weight format so (a) its bias fits the
         # int32 accumulator and (b) the output shift stays within the
         # 31-bit shifter. Without this, a channel with numerically-dead
@@ -710,10 +797,8 @@ def _lower(model: CNNModel, params: Params, amax: dict[str, float],
                          ).astype(np.int32)
         shift = np.clip(e_out - acc_e, -31, 31).astype(np.int32)
         steps.append(EngineStep(
-            name=lyr.name, kind=lyr.kind, layer=lyr, pad=pad,
+            name=lyr.name, kind=lyr.kind, layer=lyr, pad=pad, inputs=src,
             wq=np.asarray(wq), bias_q=bias_q, shift=shift,
             e_in=e_act, e_w=e_w, e_out=e_out,
-            relu=not is_last, requantize=not is_last))
-        e_act = e_out
-        hw = lyr.out_hw(hw)
+            relu=model.has_relu(lyr), requantize=not is_last))
     return steps

@@ -59,16 +59,30 @@ class LayerWorkload:
 # ---------------------------------------------------------------------------
 
 
+INPUT = "input"             # the network input's name in ``ConvLayer.inputs``
+COMPUTE = ("conv", "fc")    # the kinds with weights and MACs
+
+
 @dataclasses.dataclass(frozen=True)
 class ConvLayer:
+    """One layer of a :class:`CNNModel`. The four paper models are chains:
+    each layer reads the one before it, pads are derived from the output
+    size and every conv/fc but the last has a ReLU. A graph (a residual
+    network) names each layer's producers in ``inputs``, declares its
+    pads in ``pad`` and its ReLU in ``relu``."""
+
     name: str
     in_ch: int
     out_ch: int
     kernel: int                     # R == S (all four paper models are square)
     stride: int = 1
-    kind: Literal["conv", "fc", "pool"] = "conv"
+    # pool is max pooling; add sums its two inputs; avgpool averages
+    kind: Literal["conv", "fc", "pool", "avgpool", "add"] = "conv"
     groups: int = 1                 # AlexNet's two-tower grouped convs
     out_size: int | None = None     # explicit output H=W (valid-padding cases)
+    inputs: tuple[str, ...] = ()    # producers; () = the previous layer
+    pad: tuple[int, int] | None = None   # declared (lo, hi); None = derived
+    relu: bool | None = None        # None = every conv/fc but the last
 
     def out_hw(self, in_hw: int) -> int:
         if self.kind == "fc":
@@ -78,9 +92,12 @@ class ConvLayer:
         return in_hw // self.stride
 
     def padding(self, in_hw: int) -> tuple[int, int]:
-        """Explicit (lo, hi) spatial padding reproducing each model's
-        published output sizes (SAME for stride-1, VALID-like for the
-        stride-k stems; asymmetric when the arithmetic demands it)."""
+        """(lo, hi) spatial padding: the declared ``pad``, else the pad
+        reproducing each paper model's published output size (SAME for
+        stride-1, VALID-like for the stride-k stems; the odd pixel at
+        the end when the arithmetic demands it)."""
+        if self.pad is not None:
+            return tuple(self.pad)
         out_hw = self.out_hw(in_hw)
         need = max((out_hw - 1) * self.stride + self.kernel - in_hw, 0)
         lo = need // 2
@@ -89,27 +106,58 @@ class ConvLayer:
 
 @dataclasses.dataclass(frozen=True)
 class CNNModel:
+    """A CNN as a layer list in topological order."""
+
     name: str
     input_hw: int
     input_ch: int
     layers: tuple[ConvLayer, ...]
 
+    def sources(self) -> list[tuple[str, ...]]:
+        """Each layer's producers by name (:data:`INPUT` for the network
+        input), in layer order."""
+        out, prev = [], INPUT
+        for lyr in self.layers:
+            out.append(lyr.inputs or (prev,))
+            prev = lyr.name
+        return out
+
+    def in_hws(self) -> list[int]:
+        """Each layer's input height (= width), in layer order."""
+        hw = {INPUT: self.input_hw}
+        out = []
+        for lyr, src in zip(self.layers, self.sources()):
+            out.append(hw[src[0]])
+            hw[lyr.name] = lyr.out_hw(out[-1])
+        return out
+
+    @property
+    def output(self) -> ConvLayer:
+        """The layer whose int32 accumulators are the network's output:
+        the last conv or fc."""
+        return [lyr for lyr in self.layers if lyr.kind in COMPUTE][-1]
+
+    def has_relu(self, lyr: ConvLayer) -> bool:
+        if lyr.relu is not None:
+            return lyr.relu
+        return lyr.kind in COMPUTE and lyr.name != self.output.name
+
     def layer_workloads(self, weight_bits: int = 16) -> list[LayerWorkload]:
         """Expand the graph into per-layer workloads (paper's pi/omega)."""
         wb = weight_bits // 8
         out: list[LayerWorkload] = []
-        hw = self.input_hw
-        for lyr in self.layers:
+        for lyr, src, hw in zip(self.layers, self.sources(), self.in_hws()):
             o_hw = lyr.out_hw(hw)
-            if lyr.kind == "pool":
-                # Pooling has no MACs/weights; it is a pipeline stage that
-                # only shrinks H (paper folds it into the stride product G).
+            if lyr.kind not in COMPUTE:
+                # Pooling and adds have no MACs/weights; a pool is a
+                # pipeline stage that only shrinks H (paper folds it into
+                # the stride product G).
                 out.append(
                     LayerWorkload(
                         name=lyr.name, macs=0, weight_bytes=0,
-                        act_in_bytes=hw * hw * lyr.in_ch * wb,
+                        act_in_bytes=len(src) * hw * hw * lyr.in_ch * wb,
                         act_out_bytes=o_hw * o_hw * lyr.out_ch * wb,
-                        kind="pool", R=lyr.kernel, S=lyr.kernel,
+                        kind=lyr.kind, R=lyr.kernel, S=lyr.kernel,
                         stride=lyr.stride, C=lyr.in_ch, M=lyr.out_ch,
                         H=o_hw, W=o_hw,
                     )
@@ -138,7 +186,6 @@ class CNNModel:
                         M=lyr.out_ch, H=h, W=w,
                     )
                 )
-            hw = o_hw
         return out
 
     @property

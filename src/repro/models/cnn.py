@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.program import compile_model, float_forward, host_device
-from repro.core.workload import CNNModel
+from repro.core.workload import COMPUTE, CNNModel
 
 Params = dict[str, Any]
 
@@ -37,10 +37,8 @@ def init_params(model: CNNModel, key=None, dtype=jnp.float32) -> Params:
 def _init_params(model: CNNModel, key, dtype) -> Params:
     key = key if key is not None else jax.random.PRNGKey(0)
     p: Params = {}
-    hw = model.input_hw
     for lyr in model.layers:
-        if lyr.kind == "pool":
-            hw = lyr.out_hw(hw)
+        if lyr.kind not in COMPUTE:
             continue
         # stable per-layer fold (builtin str hash is salted per process,
         # which made init non-reproducible across runs)
@@ -55,7 +53,6 @@ def _init_params(model: CNNModel, key, dtype) -> Params:
                     lyr.out_ch), jnp.float32)
         p[lyr.name] = {"w": (w / math.sqrt(fan_in)).astype(dtype),
                        "b": jnp.zeros((lyr.out_ch,), dtype)}
-        hw = lyr.out_hw(hw)
     return p
 
 

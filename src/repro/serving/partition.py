@@ -13,9 +13,14 @@ same contiguous min-max DP the mesh allocator uses
 Stage weights come from :class:`~repro.core.allocator.LayerAlloc` — the
 single source of truth for modeled cycles — matched to steps by layer
 name: conv engines cost ``H * t_row / K`` busy cycles per frame, FC
-engines ``t_row``, pools zero (they ride with whichever compute stage the
-cut assigns them to, as on the FPGA where pooling hides inside the
-line-buffer read-out).
+engines ``t_row``, pools, adds and average pools zero (they ride with
+whichever compute stage the cut assigns them to, as on the FPGA where
+pooling hides inside the line-buffer read-out).
+
+A cut is legal only where one tensor, the output of the step before it,
+is live across it: each stage hands the next one int8 tensor. In a chain
+every cut is legal; in a residual network the cuts fall between blocks,
+never across a skip connection.
 """
 
 from __future__ import annotations
@@ -91,12 +96,20 @@ class StagePartition:
                 for i in range(self.n_stages)]
 
 
+def legal_cuts(program: EngineProgram) -> list[int]:
+    """The step indices in ``(0, len(steps))`` where a stage may start:
+    only the previous step's output is live across them."""
+    return [i for i in range(1, len(program.steps))
+            if program.skip_across(i) is None]
+
+
 def partition_from_boundaries(program: EngineProgram,
                               boundaries: Sequence[int]) -> StagePartition:
     """Build a :class:`StagePartition` for caller-chosen ``boundaries``
     (K+1 step indices covering ``[0, len(steps))``), with the same cycle
     weighting :func:`partition_program` uses — one source of truth for
-    stage_cycles/balance however the cuts were picked."""
+    stage_cycles/balance however the cuts were picked. A cut across a
+    skip connection is refused, naming the tensor it would split."""
     if program.steps is None:
         raise ValueError("plan-only program (no lowered steps) cannot be "
                          "partitioned for serving")
@@ -107,6 +120,13 @@ def partition_from_boundaries(program: EngineProgram,
         raise ValueError(
             f"boundaries {bounds} is not a contiguous cover of "
             f"[0, {len(program.steps)})")
+    for b in bounds[1:-1]:
+        skip = program.skip_across(b)
+        if skip is not None:
+            raise ValueError(
+                f"a stage cut before step {b} ({program.steps[b].name!r}) "
+                f"would split skip tensor {skip!r}: one tensor, the "
+                f"previous step's output, crosses a stage boundary")
     cycles = step_cycles(program.allocs)
     weights = [cycles.get(s.name, 0.0) for s in program.steps]
     return StagePartition(
@@ -121,28 +141,37 @@ def partition_program(program: EngineProgram,
     with near-equal modeled cycles (Algorithm 1's balance objective via
     the exact contiguous min-max DP).
 
+    Only legal cuts (:func:`legal_cuts`) are candidates: the DP runs over
+    the segments between them, each weighted by its steps' cycles.
+
     Raises when the program is plan-only (no lowered steps) or when more
-    stages than compute steps are requested — a stage of only pool steps
-    would spin on zero modeled work.
+    stages than segments holding a conv/fc step are requested — a stage
+    of only pool steps would spin on zero modeled work.
     """
     if program.steps is None:
         raise ValueError("plan-only program (no lowered steps) cannot be "
                          "partitioned for serving")
-    n_compute = sum(1 for s in program.steps if s.kind != "pool")
+    cuts = legal_cuts(program)
+    points = [0] + cuts + [len(program.steps)]
+    n_compute = sum(
+        any(s.kind in ("conv", "fc") for s in program.steps[b:e])
+        for b, e in zip(points, points[1:]))
     if not 1 <= n_stages <= n_compute:
         raise ValueError(
             f"n_stages={n_stages} outside [1, {n_compute}] "
-            f"(compute steps in the chain)")
+            f"(segments with compute steps between legal cuts)")
     cycles = step_cycles(program.allocs)
     weights = [cycles.get(s.name, 0.0) for s in program.steps]
-    bounds, _ = _partition_min_max(weights, n_stages)
+    seg_bounds, _ = _partition_min_max(
+        [sum(weights[b:e]) for b, e in zip(points, points[1:])], n_stages)
     # The DP may cut between a compute step and a trailing zero-weight
     # pool; both cuts cost the same, but keeping a pool with its producer
     # mirrors the FPGA (pooling reads out of the producing engine's line
     # buffer). Pull each boundary forward past any leading pools.
-    bounds = list(bounds)
+    bounds = [points[j] for j in seg_bounds]
+    legal = set(cuts)
     for i in range(1, n_stages):
-        while (bounds[i] < len(weights) and bounds[i] < bounds[i + 1] - 1
+        while (bounds[i] + 1 in legal and bounds[i] < bounds[i + 1] - 1
                and program.steps[bounds[i]].kind == "pool"):
             bounds[i] += 1
     return partition_from_boundaries(program, bounds)

@@ -118,14 +118,14 @@ def test_relu_folds_through_max_pool_exactly():
 
 
 def test_engine_relu_contract_is_enforced():
-    # Missing ReLU on a hidden layer: the engine cannot skip its fused
-    # epilogue ReLU.
+    # A hidden layer without a ReLU lowers with relu=False: the engine
+    # still requantizes its signed sum (a bottleneck's last conv).
     spec = tiny_spec()
     del spec["nodes"][1]
     spec["nodes"][1]["input"] = "c1"
-    with pytest.raises(UnsupportedOpError) as ei:
-        import_source(spec)
-    assert "c1" in str(ei.value)
+    model, _ = import_source(spec)
+    assert [(l.name, l.relu) for l in model.layers] == \
+        [("c1", False), ("p1", False), ("f1", False)]
     # Trailing ReLU on the final layer: the final engine emits raw
     # accumulators.
     spec = tiny_spec()
@@ -135,38 +135,81 @@ def test_engine_relu_contract_is_enforced():
     assert "f1" in str(ei.value)
 
 
+def _residual_spec(scale_b: float = 1.0):
+    """conv c1 fans out to a conv branch and a 1x1 shortcut, summed by an
+    add with a folded ReLU, then a global average pool and fc; weights
+    ride on the nodes, the shortcut's scaled by ``scale_b``."""
+    rng = np.random.default_rng(3)
+
+    def conv(name, src, cin, cout, k, scale=1.0):
+        return {"op": "conv", "name": name, "input": src,
+                "out_channels": cout, "kernel": k, "padding": k // 2,
+                "weight": scale * rng.standard_normal((k, k, cin, cout)),
+                "bias": np.zeros(cout)}
+
+    return {"name": "res", "input": {"hw": 8, "channels": 3}, "nodes": [
+        conv("c1", "input", 3, 4, 3),
+        {"op": "relu", "name": "r1", "input": "c1"},
+        conv("a", "r1", 4, 4, 3),
+        conv("b", "r1", 4, 4, 1, scale_b),
+        {"op": "add", "name": "sum", "inputs": ["a", "b"]},
+        {"op": "relu", "name": "r2", "input": "sum"},
+        {"op": "avgpool", "name": "gap", "input": "r2", "kernel": 8},
+        {"op": "flatten", "name": "fl", "input": "gap"},
+        {"op": "fc", "name": "f1", "input": "fl", "out_features": 10,
+         "weight": rng.standard_normal((4, 10)), "bias": np.zeros(10)},
+    ]}
+
+
 def test_engine_unrepresentable_ops_rejected_with_reason():
+    """Average pooling and fan-out into a residual add lower and run; an
+    add whose operands lie more than 23 bits apart cannot sum exactly in
+    int32 and is refused at compile time, naming the add."""
     spec = tiny_spec()
     spec["nodes"][2] = {"op": "avgpool", "name": "p1", "input": "r1",
                         "kernel": 2, "stride": 2}
-    with pytest.raises(UnsupportedOpError) as ei:
-        import_source(spec)
-    assert "max-only" in str(ei.value)
+    model, _ = import_source(spec)
+    assert [l.kind for l in model.layers] == ["conv", "avgpool", "fc"]
 
-    # Fan-out (residual topology) cannot map onto the linear chain.
-    spec = tiny_spec()
-    spec["nodes"] = [
-        spec["nodes"][0],
-        {"op": "relu", "name": "r1", "input": "c1"},
-        {"op": "add", "name": "res", "inputs": ["r1", "c1"]},
-    ]
+    model, params = import_source(_residual_spec())
+    assert [(l.name, l.kind, l.inputs, l.relu) for l in model.layers] == [
+        ("c1", "conv", ("input",), True), ("a", "conv", ("c1",), False),
+        ("b", "conv", ("c1",), False), ("sum", "add", ("a", "b"), True),
+        ("gap", "avgpool", ("sum",), False), ("f1", "fc", ("gap",), False)]
+    prog = compiler.quantize(model, params, seed=0)
+    frames = compiler.golden_frames(model, n=2, seed=0)
+    runners = [prog.compile_runner(route=r) for r in ("f32", "oracle")]
+    a, b = (np.asarray(r(r.quantize(frames))) for r in runners)
+    np.testing.assert_array_equal(a, b)
+
+    model, params = import_source(_residual_spec(scale_b=1e-9))
     with pytest.raises(UnsupportedOpError) as ei:
-        import_source(spec)
-    assert "c1" in str(ei.value)
+        compiler.quantize(model, params, seed=0)
+    assert "'sum'" in str(ei.value) and "23 bits" in str(ei.value)
 
 
 def test_illegal_padding_rejected_not_shifted():
-    """A declared pad the engine's output arithmetic cannot reproduce
-    must be refused — silently shifting windows would compute a
-    different model."""
+    """Declared pads are honoured, never re-derived: k3 s2 p1 on 8 pads
+    (1, 1) where the output arithmetic alone would derive (0, 1), and
+    the served integers equal the plain reference's."""
+    from bench.reference import cnn as reference
     spec = tiny_spec()
-    # k3 s2 p1 on 8: out = 4, but the engine derives need=1 -> (0, 1)
-    # from that output, not the declared (1, 1).
     spec["nodes"][0]["stride"] = 2
     spec["nodes"][0]["padding"] = 1
-    with pytest.raises(UnsupportedOpError) as ei:
-        import_source(spec)
-    assert "shift" in str(ei.value)
+    spec["nodes"][2]["kernel"] = spec["nodes"][2]["stride"] = 1
+    model, params = import_source(spec)
+    assert model.layers[0].padding(8) == (1, 1)
+    rng = np.random.default_rng(5)
+    params = {l.name: {"w": rng.standard_normal(w).astype(np.float32),
+                       "b": rng.standard_normal(w[-1]).astype(np.float32)}
+              for l, w in ((model.layers[0], (3, 3, 3, 4)),
+                           (model.layers[2], (64, 10)))}
+    calib = rng.standard_normal((1, 8, 8, 3)).astype(np.float32)
+    frames = rng.standard_normal((4, 8, 8, 3)).astype(np.float32)
+    runner = compiler.quantize(model, params, calib=calib).compile_runner()
+    net = reference.build({"graph": spec}, params, calib)
+    np.testing.assert_array_equal(np.asarray(runner(runner.quantize(frames))),
+                                  reference.accumulators(net, frames))
 
 
 # ---------------------------------------------------------------------------
