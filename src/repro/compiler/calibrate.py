@@ -92,7 +92,8 @@ def make_golden(prog: EngineProgram, frames: np.ndarray | None = None,
     if frames is None:
         frames = golden_frames(prog.model, seed=seed)
     runner = prog.compile_runner(route=route)
-    return golden_record(runner, runner(runner.quantize(np.asarray(frames))))
+    return golden_record(runner, runner(runner.quantize(np.asarray(frames)),
+                                        owned=True))
 
 
 def golden_record(runner, acc) -> dict:

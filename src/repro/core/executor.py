@@ -7,10 +7,11 @@ buffering, Fig. 2). :class:`EngineExecutor` is the software analogue on a
 frame stream:
 
 * ``submit(frame)`` micro-batches incoming frames to ``batch_size``;
-* a full micro-batch is quantized to int8 on the *host* and dispatched to
-  the jitted chain — JAX dispatch is async, so the device computes batch
-  ``k`` while the host quantizes batch ``k+1`` and argmax-decodes batch
-  ``k-1`` (the two "buffer halves" are the bounded in-flight queue);
+* a full micro-batch is copied once on the host, quantized to int8 on
+  the device (``CompiledRunner.quantize``) and dispatched to the jitted
+  chain — JAX dispatch is async, so the device computes batch ``k``
+  while the host stages batch ``k+1`` and argmax-decodes batch ``k-1``
+  (the two "buffer halves" are the bounded in-flight queue);
 * ``drain()`` flushes the partial tail batch (padded to the compiled
   shape so the runner never recompiles) and collects all results.
 
@@ -66,8 +67,8 @@ def pad_micro_batch(program: EngineProgram,
     ``[B, H, W, C]`` array) against ``program``'s input spec and pad it
     to ``batch_size`` (the fixed compiled shape) with one shared zero
     frame, without stacking it — the one batch-shaping rule both the
-    single-jit and the pipelined executor share. The quantize copies
-    each frame once, into the int8 batch's float32 scratch."""
+    single-jit and the pipelined executor share. The quantize-in copies
+    each frame once, into its float32 scratch."""
     hw = program.model.input_hw
     want = (hw, hw, program.model.input_ch)
     for f in frames:
@@ -211,9 +212,9 @@ class EngineExecutor:
 
     def _dispatch(self, frames, n_valid: int | None = None,
                   tag: object = None):
-        """Host quantize-in + async device dispatch of one micro-batch
-        (a list of frames from the pending buffer, or a ``[B, H, W, C]``
-        array), quantized straight into the int8 batch.
+        """Quantize-in + async device dispatch of one micro-batch (a
+        list of frames from the pending buffer, or a ``[B, H, W, C]``
+        array), quantized on the device straight into the int8 batch.
         Blocks only when ``max_inflight`` batches are already on device
         (the double-buffer back-pressure). Caller holds the lock."""
         if self._t0 is None:
@@ -223,7 +224,7 @@ class EngineExecutor:
         n = n_valid if n_valid is not None else len(frames)
         xq = self.runner.quantize(frames)
         t0 = time.perf_counter()
-        acc = self.runner(xq)          # async: returns a device future
+        acc = self.runner(xq, owned=True)   # async: a device future
         if self.stats.batches == 0:
             # First dispatch traces + compiles the whole chain; charge it
             # separately so steady_fps reflects the pipeline, not the jit.

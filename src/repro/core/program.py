@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
+import threading
 from typing import Any, Callable
 
 import jax
@@ -307,7 +308,9 @@ class EngineProgram:
         The jitted function is named ``serve_<model>_<start>_<stop>``
         (the model name in identifier characters), so a profiler trace
         names each stage's device program ``jit_serve_<model>_<start>_
-        <stop>``, the same in every process."""
+        <stop>``, the same in every process. A first stage also gets the
+        quantize-in, ``jit_quantize_in_<model>``
+        (:meth:`CompiledRunner.quantize`)."""
         if self.steps is None:
             raise ValueError(
                 "plan-only program (compiled without params) cannot run")
@@ -344,13 +347,26 @@ class EngineProgram:
                 return _step_oracle(xq, step, bits)
             return _walk(steps, xq, src, mac, bits)
 
-        chain.__name__ = (
-            f"serve_{re.sub(r'[^0-9A-Za-z_]', '_', self.model.name)}"
-            f"_{start}_{stop}")
+        model = re.sub(r'[^0-9A-Za-z_]', '_', self.model.name)
+        chain.__name__ = f"serve_{model}_{start}_{stop}"
         fn = jax.jit(chain, donate_argnums=(0,) if donate else ())
+        quantize_in = None
+        if start == 0:
+            e_input = self.e_input
+
+            def quantize_in(flat: jnp.ndarray, order: tuple,
+                            shape: tuple) -> jnp.ndarray:
+                with jax.named_scope("quantize_in"):
+                    x = flat.reshape((flat.shape[0],) + shape)
+                    x = x.transpose(quant.frame_axes(order))
+                    return quant.quantize_to_exponent(x, e_input, bits)
+
+            quantize_in.__name__ = f"quantize_in_{model}"
+            quantize_in = jax.jit(quantize_in, static_argnums=(1, 2))
         return CompiledRunner(program=self, route=route, donate=donate,
                               fn=fn, weights=weights, start=start,
-                              stop=stop, device=device)
+                              stop=stop, device=device,
+                              quantize_in=quantize_in)
 
 
 @dataclasses.dataclass
@@ -367,10 +383,10 @@ class CompiledRunner:
     runner's device once; passing them as arguments rather than jit
     constants keeps the compiled program free of ~100 MB of embedded
     weights. A fixed batch shape compiles exactly once (``cache_size`` is
-    the recompile guard the tests pin). Host-side quantize-in and
-    argmax/dequant-out live here so the executor can overlap them with
-    device compute; they exist only at the matching end of the chain
-    (first / last stage).
+    the recompile guard the tests pin). The quantize-in (a device
+    program fed by one host copy) and the host dequant-out live here so
+    the executor can overlap them with device compute; they exist only at
+    the matching end of the chain (first / last stage).
     """
 
     program: EngineProgram
@@ -381,10 +397,17 @@ class CompiledRunner:
     start: int = 0
     stop: int = -1          # -1 == len(program.steps) (whole chain)
     device: object = None   # jax.Device pin (None = backend default)
+    # First stage only: jit(flat float32 frames, their memory order, the
+    # frame shape in that order) -> the int8 batch (:meth:`quantize`).
+    quantize_in: Callable[[jnp.ndarray, tuple, tuple],
+                          jnp.ndarray] | None = None
+    # Batches that took the device quantize-in.
+    device_quantized_batches: int = 0
 
     def __post_init__(self):
         if self.stop < 0:
             self.stop = len(self.program.steps)
+        self._count_lock = threading.Lock()
 
     @property
     def is_first(self) -> bool:
@@ -394,35 +417,50 @@ class CompiledRunner:
     def is_last(self) -> bool:
         return self.stop == len(self.program.steps)
 
-    def quantize(self, frames) -> np.ndarray:
-        """Host-side quantize of float frames (a sequence of
-        ``[H, W, C]`` or an ``[N, H, W, C]`` array) onto the program's
-        frozen input format, into a fresh int8 batch:
-        ``quant.quantize_frames_np``, bit-identical to
-        ``quant.quantize_to_exponent``. Only the first stage consumes
-        float frames."""
+    def quantize(self, frames) -> jax.Array:
+        """Quantize float frames (a sequence of ``[H, W, C]`` or an
+        ``[N, H, W, C]`` array) onto the program's frozen input format:
+        a fresh int8 (int16 for bits=16) ``[N, H, W, C]`` batch on the
+        runner's device, bit-identical to ``quant.quantize_frames_np``.
+        Only the first stage consumes float frames.
+
+        The host copies each frame once into a float32 scratch in the
+        frames' own memory order (``quant.copy_frames_np``) and puts it
+        on the device flat, as the C-contiguous ``[N, H*W*C]`` it is:
+        lane-dense, so the transfer needs no re-tiling. The jitted
+        quantize-in, ``jit_quantize_in_<model>``, reshapes it to that
+        order, transposes to NHWC, scales, rounds half to even and
+        clamps. The memory order is its static argument: frames of one
+        order compile once (:meth:`quantize_in_cache_size`). The result
+        belongs to this call alone, so the caller may hand it to
+        ``__call__`` with ``owned=True``."""
         if not self.is_first:
             raise ValueError(
                 f"stage [{self.start}, {self.stop}) does not start the "
                 f"chain; it consumes the previous stage's quantized "
                 f"activations, not float frames")
-        return quant.quantize_frames_np(frames, self.program.e_input,
-                                        self.program.bits)
+        scratch, order = quant.copy_frames_np(frames)
+        flat = jax.device_put(scratch.reshape(len(scratch), -1), self.device)
+        with self._count_lock:
+            self.device_quantized_batches += 1
+        return self.quantize_in(flat, order, scratch.shape[1:])
 
-    def __call__(self, xq) -> jnp.ndarray:
+    def __call__(self, xq, *, owned: bool = False) -> jnp.ndarray:
         """Dispatch one quantized batch; returns the device future of the
         final accumulators (async — block or fetch to synchronize). With
         donation on, a jnp input is copied first — ``jnp.asarray`` would
         alias the caller's buffer, and donating that alias invalidates
         the caller's array (host numpy input is always staged fresh).
-        A ``device`` pin commits the input there first, so jit executes
-        the stage on that device. The donation guard copies only when
-        the input would otherwise alias: ``device_put`` onto the
-        array's *current* device can return the same buffer, but a
-        cross-device transfer already yields a fresh one — copying
-        there too would waste an activation copy per micro-batch on
-        the placed multi-device hot path."""
-        if self.donate and isinstance(xq, jax.Array) and \
+        ``owned`` says nobody else holds ``xq`` (it is
+        :meth:`quantize`'s output, handed over by the executor), so it
+        is donated as it is. A ``device`` pin commits the input there
+        first, so jit executes the stage on that device. The donation
+        guard copies only when the input would otherwise alias:
+        ``device_put`` onto the array's *current* device can return the
+        same buffer, but a cross-device transfer already yields a fresh
+        one — copying there too would waste an activation copy per
+        micro-batch on the placed multi-device hot path."""
+        if self.donate and not owned and isinstance(xq, jax.Array) and \
                 (self.device is None or xq.devices() == {self.device}):
             xq = jnp.array(xq, copy=True)
         if self.device is not None:
@@ -445,7 +483,8 @@ class CompiledRunner:
     def logits(self, x) -> np.ndarray:
         """Blocking convenience: float frames -> float logits. Bit-identical
         to ``program.run`` on the same route's arithmetic."""
-        return self.dequantize(self(self.quantize(np.asarray(x))))
+        return self.dequantize(self(self.quantize(np.asarray(x)),
+                                    owned=True))
 
     def classify(self, x) -> np.ndarray:
         """Blocking convenience: float frames -> int class ids."""
@@ -456,6 +495,11 @@ class CompiledRunner:
         """Number of distinct XLA executables behind ``fn`` (recompile
         guard: one batch shape must stay at 1)."""
         return int(self.fn._cache_size())
+
+    def quantize_in_cache_size(self) -> int:
+        """Number of XLA executables behind the quantize-in: one per
+        frame memory order seen (0 before the first batch)."""
+        return int(self.quantize_in._cache_size())
 
 
 def kernel_available(bits: int = 8) -> tuple[bool, str]:

@@ -47,9 +47,9 @@ def quantize_to_exponent(x: jnp.ndarray, e: int, bits: int = 8):
 
 def quantize_to_exponent_np(x, e: int, bits: int = 8):
     """Numpy twin of :func:`quantize_to_exponent`, whole array at once:
-    the plain reference the served quantize-in
-    (:func:`quantize_frames_np`) is held to. Bit-identical: same float32
-    multiply, same round-half-to-even, same clip
+    the plain reference :func:`quantize_frames_np` is held to.
+    Bit-identical: same float32 multiply, same round-half-to-even, same
+    clip
     (``tests/test_executor.py::test_quantize_np_twin_bit_identical``
     pins the equivalence)."""
     import numpy as np
@@ -59,29 +59,21 @@ def quantize_to_exponent_np(x, e: int, bits: int = 8):
     return q.astype(np.int8 if bits <= 8 else np.int16)
 
 
-def quantize_frames_np(frames, e: int, bits: int = 8):
-    """Quantize float frames straight into a fresh ``[N, H, W, C]``
-    int8 batch (int16 for ``bits`` 16): the host quantize-in of a served
-    micro-batch.
+def copy_frames_np(frames):
+    """Copy float frames once into a fresh float32 scratch laid out in
+    the frames' own memory order, batch outermost: the one host pass of
+    the served quantize-in.
 
     ``frames`` is a sequence of ``[H, W, C]`` frames or an
-    ``[N, H, W, C]`` array. The result equals
-    :func:`quantize_to_exponent_np` of the stacked frames: each frame is
-    copied into one float32 scratch (converted as
-    ``np.asarray(x, np.float32)`` would), which is scaled, clamped to the
-    rails and rounded half to even in place, the rounding casting into
-    the result. Clamping before ``rint`` equals clipping after it, since
-    the rails are integers and ``rint`` is monotone
-    (``tests/test_executor.py`` pins the equivalence).
-
-    One copy a frame and three whole-batch passes, with no temporaries:
-    every numpy call releases and retakes the interpreter lock, and on a
-    busy server each retake waits behind the other threads' Python work,
-    so fewer, larger calls beat cache-sized ones. Both arrays keep the
-    frames' own memory order (batch outermost, as ``np.stack`` lays it
-    out), so strided frames are read in order. The scratch and the
-    result belong to this call alone: concurrent callers share nothing,
-    and stage 0 may alias or donate the result."""
+    ``[N, H, W, C]`` array; the memory order is the first frame's (the
+    array's). Returns ``(scratch, order)``: ``scratch`` is the
+    C-contiguous ``[N] + [shape[a] for a in order]`` buffer, ``order``
+    the frame axes outermost first in memory, so
+    ``scratch.transpose(frame_axes(order))`` is the ``[N, H, W, C]``
+    stack. Each frame is converted as ``np.asarray(x, np.float32)``
+    converts it. Keeping the frames' order lets strided frames be read
+    in order (a copy into C order costs as much as the whole quantize).
+    The scratch belongs to this call alone."""
     import numpy as np
     n = len(frames)
     if isinstance(frames, np.ndarray):
@@ -91,25 +83,48 @@ def quantize_frames_np(frames, e: int, bits: int = 8):
         shape, strides = first.shape, first.strides
     else:
         raise ValueError("no frames to quantize")
-    order = sorted(range(len(shape)), key=lambda a: -abs(strides[a]))
-    back = (0,) + tuple(1 + order.index(a) for a in range(len(shape)))
-
-    def alloc(rows, dtype, make):
-        return make((rows,) + tuple(shape[a] for a in order),
-                    dtype).transpose(back)
-
-    qmax = 2 ** (bits - 1) - 1
-    out = alloc(n, np.int8 if bits <= 8 else np.int16, np.empty)
-    scratch = alloc(n, np.float32, np.empty)
+    order = tuple(sorted(range(len(shape)), key=lambda a: -abs(strides[a])))
+    scratch = np.empty((n,) + tuple(shape[a] for a in order), np.float32)
+    stack = scratch.transpose(frame_axes(order))
     for i in range(n):
         if np.shape(frames[i]) != shape:
             raise ValueError(f"frame {i} has shape {np.shape(frames[i])}, "
                              f"frame 0 {shape}")
-        scratch[i] = frames[i]
+        stack[i] = frames[i]
+    return scratch, order
+
+
+def frame_axes(order) -> tuple[int, ...]:
+    """The transpose that takes a batch laid out with its frame axes in
+    ``order`` (batch outermost, :func:`copy_frames_np`) back to
+    ``[N, H, W, C]``."""
+    return (0,) + tuple(1 + order.index(a) for a in range(len(order)))
+
+
+def quantize_frames_np(frames, e: int, bits: int = 8):
+    """Quantize float frames straight into a fresh ``[N, H, W, C]``
+    int8 batch (int16 for ``bits`` 16) on the host: the plain reference
+    the served quantize-in (``CompiledRunner.quantize``, a device
+    program) is held to.
+
+    ``frames`` is a sequence of ``[H, W, C]`` frames or an
+    ``[N, H, W, C]`` array. The result equals
+    :func:`quantize_to_exponent_np` of the stacked frames: the frames are
+    copied once into a float32 scratch (:func:`copy_frames_np`), which
+    is scaled, clamped to the rails and rounded half to even in place,
+    the rounding casting into the result. Clamping before ``rint``
+    equals clipping after it, since the rails are integers and ``rint``
+    is monotone (``tests/test_executor.py`` pins the equivalence). Both
+    arrays keep the frames' own memory order (batch outermost, as
+    ``np.stack`` lays it out)."""
+    import numpy as np
+    scratch, order = copy_frames_np(frames)
+    qmax = 2 ** (bits - 1) - 1
+    out = np.empty(scratch.shape, np.int8 if bits <= 8 else np.int16)
     np.multiply(scratch, np.float32(2.0 ** (-e)), out=scratch)
     np.clip(scratch, -qmax - 1, qmax, out=scratch)
     np.rint(scratch, out=out, casting="unsafe")
-    return out
+    return out.transpose(frame_axes(order))
 
 
 def quantize_po2(x: jnp.ndarray, axis: int, bits: int = 8):

@@ -250,7 +250,7 @@ def install_stage_fault(px, stage: int, at_call: int):
             self.calls = 0
             self._lock = threading.Lock()
 
-        def __call__(self, payload):
+        def __call__(self, payload, **kw):
             with self._lock:
                 self.calls += 1
                 n = self.calls
@@ -258,7 +258,7 @@ def install_stage_fault(px, stage: int, at_call: int):
                 raise StageKilled(
                     f"injected fault: stage {stage} died on its "
                     f"batch {n}")
-            return self._runner(payload)
+            return self._runner(payload, **kw)
 
         def __getattr__(self, attr):
             # quantize/dequantize and anything else the pipeline needs.

@@ -202,10 +202,11 @@ class PipelineExecutor:
                      n_valid: int, tag: object = None) -> None:
         """Dispatch one float micro-batch (a sequence of ``[H, W, C]``
         frames or a ``[B, H, W, C]`` array), padded with zero frames to
-        the compiled batch size if short. Quantizes on the calling
-        thread straight into a fresh int8 batch — the host half of the
-        stage-0 double buffer — and blocks when the stage-0 queue is
-        full (backpressure).
+        the compiled batch size if short. The quantize-in runs from the
+        calling thread — the host half of the stage-0 double buffer: one
+        copy of the frames, their transfer and the dispatch of the
+        device quantize that makes the fresh int8 batch — and it blocks
+        when the stage-0 queue is full (backpressure).
 
         The ``serve.quantize`` span's ``batch`` is the sequence number
         this batch takes when it is the only producer, as a frontend's
@@ -352,7 +353,9 @@ class PipelineExecutor:
                 try:
                     with TraceAnnotation("serve.stage", batch=seq, stage=i):
                         t0 = time.perf_counter()
-                        out = runner(payload)
+                        # Stage 0's payload is its quantize-in's fresh
+                        # output: nobody else holds it.
+                        out = runner(payload, owned=i == 0)
                         with TraceAnnotation("serve.stage.ready.wait",
                                              batch=seq, stage=i):
                             out.block_until_ready()

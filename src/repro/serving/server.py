@@ -575,10 +575,14 @@ class Server:
 
         ``stage_executables`` lists, per replica (one list unreplicated),
         each stage's compiled executables: a value above 1 names the
-        stage that compiled a second shape."""
+        stage that compiled a second shape. ``quantize_in_executables``
+        lists each replica's quantize-in executables, one per frame
+        memory order seen; ``device_quantized_batches`` counts the
+        batches that took the device quantize-in."""
         models: dict[str, dict] = {}
         samples: dict[str, list] = {}
         for name, rt in self._runtimes.items():
+            reps = getattr(rt.executor, "replicas", None) or [rt.executor]
             models[name] = {
                 "steady_fps": round(rt.steady_fps, 3),
                 "modeled_fps_alg1": round(rt.program.fps(), 3),
@@ -589,8 +593,11 @@ class Server:
                 "stages": rt.executor.partition.n_stages,
                 "stage_executables": [
                     [runner.cache_size() for runner in rep.runners]
-                    for rep in getattr(rt.executor, "replicas", None)
-                    or [rt.executor]],
+                    for rep in reps],
+                "quantize_in_executables": [
+                    rep.runners[0].quantize_in_cache_size() for rep in reps],
+                "device_quantized_batches": sum(
+                    rep.runners[0].device_quantized_batches for rep in reps),
                 **{k: 0 for k in _OUTCOME_KEYS},
                 "latency_ms_p50": None,
                 "latency_ms_p95": None,

@@ -2,6 +2,7 @@
 (all routes), streaming micro-batch semantics, recompile/donation guards,
 and the YOLO/ZF golden int8 outputs."""
 
+import dataclasses
 import importlib.util
 import os
 import zlib
@@ -283,6 +284,93 @@ def test_quantize_frames_np_equals_padded_stack(dtype, n, form, layout):
                 padded, quant.quantize_to_exponent_np(
                     np.concatenate([stacked, np.zeros(
                         (8 - n,) + x.shape[1:], stacked.dtype)]), e, bits))
+
+
+@pytest.fixture(scope="module")
+def quantize_in_runners():
+    """First-stage runners of ``_tiny``'s program, pinned to the first
+    device, one per input exponent and width, made on demand and shared
+    by the cases below (so each frame order and batch compiles once)."""
+    prog, _ = _tiny()
+    runners = {}
+
+    def get(e, bits):
+        if (e, bits) not in runners:
+            runners[e, bits] = dataclasses.replace(
+                prog, e_input=e, bits=bits).compile_stage_runner(
+                    0, 1, device=jax.devices()[0])
+        return runners[e, bits]
+    return get
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "strided", "permuted"])
+@pytest.mark.parametrize("form", ["list", "array"])
+@pytest.mark.parametrize("n", [1, 5, 8])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64, np.uint8])
+def test_device_quantize_in_equals_host_quantize(quantize_in_runners, dtype,
+                                                 n, form, layout):
+    """``CompiledRunner.quantize`` (one copy in the frames' own order, a
+    flat transfer, the jitted quantize-in) == ``quant.quantize_frames_np``
+    bit for bit, as a fresh array on the runner's device: for every input
+    dtype and memory layout, a sequence or an array, short batches padded
+    to 8 with a shared zero frame and full ones, every exponent and
+    width, with ties and rails planted."""
+    from repro.core import quant
+    for e in (-3, 0, 2):
+        for bits in (8, 16):
+            runner = quantize_in_runners(e, bits)
+            x = _planted_frames(dtype, e, bits, n, layout)
+            zero = np.zeros(x.shape[1:], np.float32)
+            for frames in (list(x) if form == "list" else x,
+                           [*x, *[zero] * (8 - n)]):
+                got = runner.quantize(frames)
+                want = quant.quantize_frames_np(frames, e, bits)
+                assert isinstance(got, jax.Array)
+                assert got.devices() == {jax.devices()[0]}
+                assert got.dtype == want.dtype
+                np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_quantize_in_compiles_once_per_frame_order():
+    """The quantize-in compiles once for frames of one memory order,
+    lists and arrays, full and padded alike, and once more for a second
+    order; each call counts one device-quantized batch."""
+    from repro.core.executor import pad_micro_batch
+    prog, frames = _tiny()
+    runner = prog.compile_runner(route="f32")
+    assert runner.quantize_in_cache_size() == 0
+    runner.quantize(frames[:8])
+    runner.quantize(list(frames[3:]))
+    runner.quantize(pad_micro_batch(prog, frames[:3], 8))
+    assert runner.quantize_in_cache_size() == 1
+    permuted = np.ascontiguousarray(
+        frames[:8].transpose(1, 3, 0, 2)).transpose(2, 0, 3, 1)
+    runner.quantize(permuted)
+    runner.quantize(list(permuted))
+    assert runner.quantize_in_cache_size() == 2
+    assert runner.device_quantized_batches == 5
+
+
+def test_donating_runner_copies_a_callers_array_and_donates_its_own():
+    """With donation on, a device array the caller passes is copied
+    first and stays valid; one handed over as ``owned`` (the quantize-in's
+    fresh output) is donated as it is. The stage keeps its input's shape,
+    so the donation is usable, on a CPU device too."""
+    import warnings
+    prog, _ = _tiny()
+    mid = prog.compile_stage_runner(2, 3, route="f32", donate=True)
+    x = jnp.asarray(np.random.default_rng(3).integers(
+        -128, 128, (4, 8, 8, 8)), jnp.int8)
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")   # "donated buffers were not usable"
+        want = np.asarray(mid(x))
+        assert not x.is_deleted()
+        np.testing.assert_array_equal(np.asarray(mid(x)), want)
+        mine = jnp.array(x, copy=True)
+        np.testing.assert_array_equal(np.asarray(mid(mine, owned=True)),
+                                      want)
+    assert mine.is_deleted() and not x.is_deleted()
+    assert mid.cache_size() == 1
 
 
 def test_quantize_frames_np_refuses_bad_frames():

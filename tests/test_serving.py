@@ -167,6 +167,77 @@ def test_pipelined_mid_block_boundary_bit_identical():
         np.testing.assert_array_equal(got, want, err_msg=str(bounds))
 
 
+def _tiny_residual():
+    """``bench/tests/tiny_residual.json``'s graph (a strided stem, max
+    pool, two bottlenecks with skip adds, average pool, fc) at 32x32x3,
+    seeded weights; 10 frames."""
+    import json
+    from pathlib import Path
+
+    from repro.compiler import import_source, quantize
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" / "tests"
+                      / "tiny_residual.json").read_text())
+    model, _ = import_source(cfg["graph"])
+    prog = quantize(model, bits=8, seed=5)
+    frames = np.random.default_rng(5).standard_normal(
+        (10, 32, 32, 3)).astype(np.float32)
+    return prog, frames
+
+
+def _host_quantized_logits(runners, frames, batch):
+    """Logits as the host quantize-in served them: each batch padded
+    with zero frames, ``quant.quantize_frames_np`` on the host, the int8
+    batch chained through the stage runners."""
+    from repro.core import quant
+    out = []
+    for i in range(0, len(frames), batch):
+        chunk = list(frames[i:i + batch])
+        n = len(chunk)
+        chunk += [np.zeros_like(chunk[0])] * (batch - n)
+        x = quant.quantize_frames_np(chunk, runners[0].program.e_input,
+                                     runners[0].program.bits)
+        for r in runners:
+            x = r(x)
+        out.append(runners[-1].dequantize(x)[:n])
+    return np.concatenate(out)
+
+
+@pytest.mark.parametrize("route", ["f32", "oracle", "kernel"])
+@pytest.mark.parametrize("model", ["tiny", "tiny_residual"])
+def test_served_logits_equal_host_quantized_chain(model, route):
+    """The pipeline and ``Server`` serve, through the device quantize-in,
+    the logits the host quantize-in gave: ``quantize_frames_np`` chained
+    through the same stage runners, bit for bit, for a chain and a
+    residual graph on every route; every served batch took the device
+    quantize-in, compiled once."""
+    from repro.serving import ProgramRegistry, ServerConfig, build_server
+    prog, frames = _tiny() if model == "tiny" else _tiny_residual()
+    with PipelineExecutor(prog, stages=2, batch_size=4, route=route,
+                          output="logits") as px:
+        got = np.stack(px.serve(list(frames)))
+        want = _host_quantized_logits(px.runners, frames, 4)
+    np.testing.assert_array_equal(got, want)
+    assert px.runners[0].device_quantized_batches == 3
+    assert px.runners[0].quantize_in_cache_size() == 1
+
+    reg = ProgramRegistry()
+    reg.register(model, prog)
+    srv = build_server(reg, ServerConfig(batch=4, stages=2, route=route,
+                                         output="logits", max_wait_ms=50.0),
+                       streams={model: frames})
+    try:
+        got = np.stack([r.result(timeout=120) for r in
+                        [srv.submit(model, f) for f in frames]])
+        want = _host_quantized_logits(srv.runtime(model).executor.runners,
+                                      frames, 4)
+        row = srv.stats()["models"][model]
+    finally:
+        srv.close()
+    np.testing.assert_array_equal(got, want)
+    assert row["quantize_in_executables"] == [1]
+    assert row["device_quantized_batches"] >= 3
+
+
 def test_stage_devices_round_robin():
     """Placement policy: stage i -> devices[i % n], default jax.devices(),
     bad inputs refused."""

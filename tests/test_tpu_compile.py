@@ -93,3 +93,25 @@ def test_int8_engine_compiles_for_v5e(one_chip, label, case):
                                     emit_int32=emit)
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text(), label
+
+
+@pytest.mark.parametrize("hw,order", [(227, (0, 2, 1)), (227, (0, 1, 2)),
+                                      (224, (0, 1, 2))],
+                         ids=["227-hcw", "227-hwc", "224-hwc"])
+def test_quantize_in_compiles_for_v5e(one_chip, hw, order):
+    """The first stage's quantize-in at the served batch and the benchmark
+    cells' frame sizes, for frames laid out (H, C, W) as a pool fetched
+    from the chip is, and C-ordered as users send them: a flat float32
+    batch in, the int8 NHWC batch out."""
+    from repro.core.program import compile_model
+    from repro.models import cnn
+    m = W.CNNModel("qin", 8, 3, (W.ConvLayer("fc", 8 * 8 * 3, 10, 1,
+                                             kind="fc"),))
+    prog = compile_model(m, cnn.init_params(m, jax.random.PRNGKey(0)),
+                         bits=8, calib_batch=jnp.ones((1, 8, 8, 3)))
+    runner = prog.compile_stage_runner(0, 1)
+    shape = tuple((hw, hw, 3)[a] for a in order)
+    flat = _sds((SERVE_BATCH, hw * hw * 3), jnp.float32, one_chip)
+    compiled = runner.quantize_in.lower(flat, order, shape).compile()
+    (out,) = jax.tree.leaves(compiled.out_info)
+    assert out.shape == (SERVE_BATCH, hw, hw, 3) and out.dtype == jnp.int8

@@ -153,6 +153,29 @@ def test_stats_count_one_executable_per_stage_after_warm_up(replicas):
     assert row["stage_executables"] == [[1] * STAGES] * replicas
 
 
+@pytest.mark.parametrize("replicas", [1, 2])
+def test_stats_count_quantize_in_executables_and_batches(replicas):
+    """``Server.stats()`` lists one quantize-in executable per replica
+    and counts every batch that took the device quantize-in: each one
+    the replicas' pipelines dispatched."""
+    srv, frames = _server(replicas)
+    try:
+        before = srv.stats()["models"][NAME]
+        for r in [srv.submit(NAME, frames[i]) for i in range(3 * BATCH)]:
+            r.result(timeout=120)
+        row = srv.stats()["models"][NAME]
+        ex = srv.runtime(NAME).executor
+        dispatched = sum(rep.stats.batches for rep in
+                         getattr(ex, "replicas", None) or [ex])
+    finally:
+        srv.close()
+    assert row["quantize_in_executables"] == [1] * replicas
+    assert before["device_quantized_batches"] > 0      # the warm-up's
+    assert row["device_quantized_batches"] - before[
+        "device_quantized_batches"] >= 3
+    assert row["device_quantized_batches"] >= dispatched >= 3
+
+
 def _module_name(runner, x) -> str:
     text = runner.fn.lower(x, runner.weights).as_text()
     return re.search(r"module @(\w+)", text).group(1)
@@ -171,6 +194,20 @@ def test_stage_jit_names_are_distinct_per_stage_and_stable():
     n = len(prog.steps)
     assert names[0] == names[1] == [f"jit_serve_tiny_net_0_{cut}",
                                     f"jit_serve_tiny_net_{cut}_{n}"]
+
+
+def test_quantize_in_jit_has_a_fixed_name():
+    """The first stage's quantize-in lowers to ``jit_quantize_in_<model>``
+    with its ops under the ``quantize_in`` scope, the same in every
+    runner."""
+    prog = _program()
+    flat = jnp.zeros((BATCH, HW * HW * CH), jnp.float32)
+    for _ in range(2):
+        text = prog.compile_stage_runner(0, 1).quantize_in.lower(
+            flat, (0, 1, 2), (HW, HW, CH)).as_text(debug_info=True)
+        assert re.search(r"module @(\w+)", text).group(1) == \
+            "jit_quantize_in_tiny_net"
+        assert "quantize_in/" in text
 
 
 def test_pallas_kernel_has_a_fixed_name():
